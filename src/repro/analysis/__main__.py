@@ -30,9 +30,11 @@ Exit status 0 iff zero unsuppressed errors; findings land in
 import os
 
 # Before ANY jax import: the dist audit shards over 8 simulated host devices
-# (jax locks the device count at first backend init, the same reason
-# launch/dryrun.py sets its flag at the very top).
+# (jax locks the device count and the backend at first init, the same reason
+# launch/dryrun.py sets its flags at the very top). The audit is compile-only
+# and pins the CPU backend so it never claims an accelerator.
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse
 import dataclasses
@@ -47,6 +49,7 @@ from repro.analysis import hlo_lint, jaxpr_lint
 from repro.analysis.rules import (Report, apply_suppressions,
                                   default_suppressions, dump_report,
                                   render_report)
+from repro.launch.mesh import make_mesh
 
 CORE_N = 8          # nodes in the core-engine audit ensemble
 CORE_D = 64 * 1024  # (CORE_N, CORE_D) f32 = 2 MB per carry leaf: over the
@@ -147,7 +150,7 @@ def audit_dist(variant: str, arch: str, use_kernel: bool,
                           "use_kernel": use_kernel,
                           "backend": jax.default_backend()})
     cfg = dataclasses.replace(get_config(arch).reduced(), n_nodes=4)
-    prod = jax.make_mesh((4, 2), ("data", "model"))
+    prod = make_mesh((4, 2), ("data", "model"))
     mesh = sh.train_mesh(prod, cfg)
     dcfg = DistSparqConfig(H=2, variant=variant, frac=0.25,
                            use_kernel=use_kernel)
@@ -290,7 +293,7 @@ def audit_serve(arch: str) -> List[Report]:
     from repro.models.config import InputShape
 
     cfg = get_config(arch).reduced()
-    prod = jax.make_mesh((4, 2), ("data", "model"))
+    prod = make_mesh((4, 2), ("data", "model"))
     mesh = sh.serve_mesh(prod)
     axes = list(mesh.shape.items())
     roles = {"data": "batch", "model": "tensor"}

@@ -330,6 +330,15 @@ def build_sparq(cfg, mesh, dcfg: DistSparqConfig
             "sync_rounds": jnp.int32(0), "triggers": jnp.int32(0),
         }
 
+    def local_rows_compress(rows):
+        return kernel_ops.sign_topk_ensemble(rows, k_b, lowering=lowering)
+
+    # Mosaic kernels are not partitioned by GSPMD, so the kernel is mapped
+    # over the node axis: each device compresses the node rows it holds
+    kernel_rows = jax.shard_map(local_rows_compress, mesh=mesh,
+                                in_specs=row_spec, out_specs=row_spec,
+                                check_vma=False)
+
     def loss_fn(row, b):
         return lm_loss(cfg, unravel(row), b)[0]
 
@@ -407,11 +416,10 @@ def build_sparq(cfg, mesh, dcfg: DistSparqConfig
             trigf = trig.astype(jnp.float32)
 
             if dcfg.use_kernel:
-                # ONE fused blockwise dispatch over the whole padded buffer
+                # ONE fused blockwise dispatch per device over its node rows
                 # (kernels/ops.py; == vmapping BlockTopFrac row-by-row).
                 # Trigger gating happens below: q is linear in the 0/1 gate.
-                q = kernel_ops.sign_topk_ensemble(diff, k_b,
-                                                  lowering=lowering)
+                q = kernel_rows(diff)
             else:
                 # generic registry operator over the TRUE flat vector (n, D)
                 # rows — one global operator application per node, matching

@@ -13,7 +13,10 @@ K2 lowering-flag-hygiene contract (repro.analysis).
 Legs:
 
 * ``"pallas"``    — ``pl.pallas_call(..., interpret=False)``: the Mosaic
-  kernel, TPU only (CPU XLA has no Mosaic compiler).
+  kernel, TPU only (CPU XLA has no Mosaic compiler). It compiles for TPU v5e
+  at real model widths (tests/test_tpu_compile.py compiles it for a
+  described chip); a caller on a sharded mesh maps it over the mesh with
+  ``shard_map``, since GSPMD does not partition Mosaic kernels.
 * ``"interpret"`` — ``pl.pallas_call(..., interpret=True)``: the Pallas
   interpreter, runs anywhere; structural ground truth, slow.
 * ``"xla"``       — the SAME blockwise math as a plain jnp program compiled

@@ -63,12 +63,35 @@ def _row_threshold(av: jax.Array, k_b: int) -> jax.Array:
     return jax.lax.bitcast_convert_type(bits, jnp.float32)[:, None]
 
 
+def _first_ties(tie: jax.Array, quota: jax.Array) -> jax.Array:
+    """The lowest-index ``quota`` lanes of ``tie`` per row, i.e. exactly
+    ``tie & (cumsum(tie) <= quota)``, without cumsum (Mosaic has no
+    lowering for it): an 11-step bitwise search finds the largest lane
+    cutoff m in [0, BLOCK] with count(tie[:, :m]) <= quota by compare +
+    row-sum passes, and the prefix count is nondecreasing, so the lanes
+    below m are the ones whose rank is within quota.
+    tie: (rows, B) bool; quota: (rows, 1) int32 >= 0."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, tie.shape, 1)
+    tie_i = tie.astype(jnp.int32)
+    top = tie.shape[1].bit_length() - 1
+
+    def body(i, m):
+        cand = m | jnp.left_shift(jnp.int32(1), top - i)
+        cnt = jnp.sum(jnp.where(lane < cand, tie_i, 0), axis=1,
+                      keepdims=True)
+        return jnp.where((cand <= tie.shape[1]) & (cnt <= quota), cand, m)
+
+    cut = jax.lax.fori_loop(0, top + 1, body,
+                            jnp.zeros(quota.shape, jnp.int32))
+    return jnp.logical_and(tie, lane < cut)
+
+
 def _block_compress(diff: jax.Array, trig: jax.Array, k_b: int
                     ) -> Tuple[jax.Array, jax.Array]:
     """Exact-k blockwise SignTopK on f32 rows.
 
     diff: (rows, BLOCK) f32; trig: scalar f32 in {0., 1.}. Returns
-    (q (rows, BLOCK) f32, per-row scale (rows,) f32 — already trig-gated).
+    (q (rows, BLOCK) f32, per-row scale (rows, 1) f32 — already trig-gated).
     The selected index set per row equals ``jax.lax.top_k(|diff|, k_b)``'s
     (strictly-above-threshold entries first, then lowest-index ties)
     restricted to nonzero lanes, so |support| <= k_b and a k_b-entry payload
@@ -82,14 +105,13 @@ def _block_compress(diff: jax.Array, trig: jax.Array, k_b: int
                                           jnp.logical_not(gt)), pos)
     # fill the remaining quota with the LOWEST-index ties (top_k order)
     quota = k_b - jnp.sum(gt.astype(jnp.int32), axis=1, keepdims=True)
-    rank = jnp.cumsum(tie.astype(jnp.int32), axis=1)
-    mask = jnp.logical_or(gt, jnp.logical_and(tie, rank <= quota))
+    mask = jnp.logical_or(gt, _first_ties(tie, quota))
     nsel = jnp.sum(mask.astype(jnp.float32), axis=1, keepdims=True)
     scale = (jnp.sum(jnp.where(mask, av, 0.0), axis=1, keepdims=True)
              / jnp.maximum(nsel, 1.0))
     signs = jnp.where(diff >= 0, 1.0, -1.0)
     q = jnp.where(mask, trig * scale * signs, 0.0)
-    return q, (trig * scale[:, 0]).astype(jnp.float32)
+    return q, (trig * scale).astype(jnp.float32)
 
 
 def _sign_topk_kernel(xh_ref, xe_ref, trig_ref, q_ref, xe_new_ref, scale_ref,
@@ -115,7 +137,7 @@ def _sign_topk_xla(x_half: jax.Array, x_hat: jax.Array, trig: jax.Array,
     diff = x_half.astype(jnp.float32) - x_hat.astype(jnp.float32)
     q32, scale = _block_compress(diff, trig, k_b)
     q = q32.astype(x_half.dtype)
-    return q, x_hat + q, scale
+    return q, x_hat + q, scale[:, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("k_b", "interpret", "lowering"))
@@ -136,7 +158,7 @@ def sign_topk_blocks(x_half: jax.Array, x_hat: jax.Array, trig: jax.Array,
     rows = min(BLOCK_ROWS, n)
     assert n % rows == 0
     grid = (n // rows,)
-    return pl.pallas_call(
+    q, xe_new, scale = pl.pallas_call(
         functools.partial(_sign_topk_kernel, k_b=k_b),
         grid=grid,
         in_specs=[
@@ -147,12 +169,15 @@ def sign_topk_blocks(x_half: jax.Array, x_hat: jax.Array, trig: jax.Array,
         out_specs=[
             pl.BlockSpec((rows, BLOCK), lambda i: (i, 0)),
             pl.BlockSpec((rows, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((rows,), lambda i: (i,)),
+            # 2-D (rows, 1) block: Mosaic refuses rank-1 blocks that are not
+            # a multiple of 128 lanes
+            pl.BlockSpec((rows, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n, BLOCK), x_half.dtype),
             jax.ShapeDtypeStruct((n, BLOCK), x_half.dtype),
-            jax.ShapeDtypeStruct((n,), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
         interpret=(lw == "interpret"),
     )(x_half, x_hat, trig_arr.reshape(1))
+    return q, xe_new, scale[:, 0]

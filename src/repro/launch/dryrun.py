@@ -1,7 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count on first init). Everything below is ordinary.
+os.environ["JAX_PLATFORMS"] = "cpu"
+# The lines above MUST run before any other import (jax locks the device
+# count and the backend on first init): the dry-run compiles for 512 fake
+# host devices and never claims an accelerator. Everything below is ordinary.
 """Multi-pod dry-run: lower + compile every (arch x input-shape x mesh) combination
 against the production mesh, and extract the roofline terms from the compiled
 artifact (no device allocation — inputs are ShapeDtypeStructs).

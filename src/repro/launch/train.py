@@ -1,9 +1,14 @@
 """End-to-end decentralized training driver.
 
 Runs SPARQ-SGD over the (node, fsdp, model) logical mesh with the synthetic
-heterogeneous token pipeline, metrics logging, and checkpointing. On this CPU
-container, pass ``--devices 8 --reduced`` for a runnable demonstration; on a
-real pod, omit ``--devices`` (jax discovers the TPU mesh) and drop ``--reduced``.
+heterogeneous token pipeline, metrics logging, and checkpointing. On a CPU,
+pass ``--devices 8 --reduced`` for a runnable demonstration. On TPU chips,
+omit ``--devices`` (jax discovers the chips) and drop ``--reduced``: at
+published widths one graph node takes a whole v5e chip (qwen1.5-0.5b's step
+peaks near 15 GB of the chip's 16 GB), so pass ``--nodes`` equal to the chip
+count. A ``--nodes`` above the device count stacks several node rows on one
+device, which is how a reduced-width ring runs on a single chip.
+:func:`run` is the CLI's body for in-process callers (``chip_smoke.py``).
 
   PYTHONPATH=src python -m repro.launch.train --arch qwen1.5-0.5b \
       --devices 8 --reduced --steps 40 --log-every 5
@@ -18,11 +23,18 @@ step counter, bits/trigger accounting) so ``--resume`` continues the exact
 trajectory instead of silently resetting momentum and the step counter.
 """
 import argparse
+import dataclasses
+import math
 import os
 import sys
+from typing import Any, Dict, NamedTuple, Optional, Sequence
+
+# fixed, git-ignored compile-cache directory of the checkout
+CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.abspath(__file__), "..", "..", "..", "..", ".jax_cache"))
 
 
-def _parse() -> argparse.Namespace:
+def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen1.5-0.5b")
     ap.add_argument("--steps", type=int, default=40)
@@ -92,15 +104,33 @@ def _parse() -> argparse.Namespace:
     ap.add_argument("--resume", action="store_true",
                     help="continue from the latest checkpoint in --ckpt-dir "
                          "(full train state: params, x_hat, opt, t, bits)")
-    return ap.parse_args()
+    return ap.parse_args(argv)
 
 
-def main() -> int:
-    args = _parse()
-    if args.devices:
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
-    import dataclasses
+def setup_compile_cache() -> None:
+    """Persistent compile cache: where ``JAX_COMPILATION_CACHE_DIR`` is set,
+    JAX reads it itself and nothing is set here; otherwise the cache lives at
+    a fixed directory of the checkout (a path that moved would never hit)."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+
+
+class TrainResult(NamedTuple):
+    """What :func:`run` hands an in-process caller."""
+
+    metrics: Optional[Dict[str, float]]  # last step's; None if no step ran
+    state: Any                           # final train state (on device)
+    train_step: Any                      # build_sparq's step (.lowering ...)
+    compiled: Any                        # the AOT-compiled step executable
+    compile_seconds: float               # lower + compile of the step
+    seconds_per_step: Optional[float]    # steady steps, after the first
+
+
+def run(args: argparse.Namespace,
+        devices: Optional[Sequence[Any]] = None) -> TrainResult:
+    """Train per ``args`` (see :func:`parse_args`) on ``devices`` (default: all
+    of ``jax.devices()``); the body of the CLI, callable in-process."""
     import time
 
     import jax
@@ -115,6 +145,7 @@ def main() -> int:
     from repro.data.synthetic import TokenPipeline
     from repro.dist import sharding as sh
     from repro.dist.sparq_dist import DistSparqConfig, build_sparq
+    from repro.launch.mesh import make_mesh
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -122,21 +153,16 @@ def main() -> int:
     if args.nodes:
         cfg = dataclasses.replace(cfg, n_nodes=args.nodes)
 
-    ndev = len(jax.devices())
-    # factor the device array as (node, fsdp, model): greedily give model
-    # parallelism what n_nodes leaves over
-    n_nodes = min(cfg.n_nodes, ndev)
-    while ndev % n_nodes:
-        n_nodes -= 1
-    rest = ndev // n_nodes
-    model_par = 1
-    for m in (16, 8, 4, 2, 1):
-        if rest % m == 0:
-            model_par = m
-            break
-    prod_mesh = jax.make_mesh((ndev // model_par, model_par),
-                              ("data", "model"))
-    cfg = dataclasses.replace(cfg, n_nodes=n_nodes)
+    devices = list(jax.devices() if devices is None else devices)
+    ndev = len(devices)
+    # factor the devices as (node, fsdp, model): the node axis is the largest
+    # factor shared with the node count (more graph nodes than devices stack
+    # several node rows on one device), model parallelism gets what is left
+    n_nodes = cfg.n_nodes
+    rest = ndev // math.gcd(n_nodes, ndev)
+    model_par = next(m for m in (16, 8, 4, 2, 1) if rest % m == 0)
+    prod_mesh = make_mesh((ndev // model_par, model_par), ("data", "model"),
+                          devices=devices)
     mesh = sh.train_mesh(prod_mesh, cfg)
 
     try:
@@ -174,8 +200,10 @@ def main() -> int:
     init_fn, train_step, state_specs, pshape = build_sparq(cfg, mesh, dcfg)
     n_params = sum(np.prod(leaf.shape) for leaf in jax.tree.leaves(pshape))
     plan = init_fn.plan   # the engine's own plan, not a re-resolution
+    dev = devices[0]
     print(f"[train] mesh {dict(mesh.shape)}  arch={cfg.arch_id} "
-          f"(~{n_params / 1e6:.1f}M params/node)")
+          f"(~{n_params / 1e6:.1f}M params/node, {train_step.n_nodes} nodes) "
+          f"on {ndev} x {dev.platform}/{dev.device_kind}")
     print(f"[train] gossip plan {plan.name} (R={plan.R}) "
           f"delta_eff={plan.delta_eff:.4f}")
     if not faults.is_null:
@@ -206,7 +234,9 @@ def main() -> int:
         print(f"[train] resumed full train state from step {last} "
               f"(t={int(state['t'])}, bits={float(state['bits']):.3e})")
     else:
-        state = jax.device_put(init_fn(jax.random.PRNGKey(0)), ssh)
+        # built in place on its shardings: an eager init would hold the
+        # pytree, its raveled copy and the tiled buffers on one device
+        state = jax.jit(init_fn, out_shardings=ssh)(jax.random.PRNGKey(0))
 
     pipe = TokenPipeline(vocab_size=cfg.vocab_size, seq_len=args.seq_len,
                          batch_per_node=args.batch_per_node,
@@ -219,18 +249,16 @@ def main() -> int:
                        is_leaf=lambda x: isinstance(x, P))
     step = jax.jit(train_step, in_shardings=(ssh, bsh),
                    donate_argnums=(0,))
+    t0 = time.perf_counter()
+    compiled = step.lower(state, b0).compile()
+    compile_s = time.perf_counter() - t0
+    print(f"[train] step compiled in {compile_s:.2f}s "
+          f"(lowering={train_step.lowering})")
 
     if args.lint:
-        # audit THIS jitted step: .lower() shares the trace cache with the
-        # training loop's calls, so the audit adds one AOT compile but no
-        # extra trace (the repro.analysis retrace gate relies on the same)
         from repro.analysis.contracts import run_contract_lint
         from repro.analysis.hlo_lint import run_lint
-        state_sds = jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)
-        hlo = step.lower(state_sds, jax.tree.map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-            b0)).compile().as_text()
+        hlo = compiled.as_text()
         lint = run_lint(
             hlo, donated_params=range(len(jax.tree.leaves(state))),
             use_kernel=train_step.use_kernel,
@@ -252,16 +280,19 @@ def main() -> int:
               "(lowering + theory contracts)")
 
     metrics = None
-    t0 = time.time()
+    t_first = None
     for i in range(start, args.steps):
         batch = jax.device_put(pipe.global_batch(i), bsh)
-        state, metrics = step(state, batch)
+        state, metrics = compiled(state, batch)
+        if t_first is None:
+            # steady timing starts once the first step has finished
+            jax.block_until_ready(metrics)
+            t_first = time.perf_counter()
         if (i + 1) % args.log_every == 0:
             m = {k: float(v) for k, v in metrics.items()}
             print(f"[train] step {i+1:5d} loss {m['loss']:.4f} "
                   f"eta {m['eta']:.4f} bits {m['bits']:.3e} "
-                  f"triggers {m['triggers']:.0f} "
-                  f"({(time.time()-t0)/(i+1-start):.2f}s/step)")
+                  f"triggers {m['triggers']:.0f}")
         if args.ckpt_dir and args.ckpt_every and \
                 (i + 1) % args.ckpt_every == 0:
             path = ckpt.save(args.ckpt_dir, i + 1, jax.device_get(state))
@@ -271,10 +302,25 @@ def main() -> int:
         # resume): there is no final metrics dict to report
         print(f"[train] DONE no steps run (start={start}, "
               f"steps={args.steps})")
-        return 0
+        return TrainResult(None, state, train_step, compiled, compile_s, None)
+    jax.block_until_ready((state, metrics))
+    n_steady = args.steps - start - 1
+    step_s = ((time.perf_counter() - t_first) / n_steady
+              if n_steady else None)
     m = {k: float(v) for k, v in metrics.items()}
     print(f"[train] DONE loss={m['loss']:.4f} total_bits={m['bits']:.3e} "
-          f"trigger_events={m['triggers']:.0f}")
+          f"trigger_events={m['triggers']:.0f}"
+          + (f" ({step_s:.4f}s/step steady)" if step_s is not None else ""))
+    return TrainResult(m, state, train_step, compiled, compile_s, step_s)
+
+
+def main(argv: Optional[Sequence[str]] = None) -> int:
+    args = parse_args(argv)
+    if args.devices:
+        os.environ["XLA_FLAGS"] = (
+            f"--xla_force_host_platform_device_count={args.devices}")
+    setup_compile_cache()
+    run(args)
     return 0
 
 

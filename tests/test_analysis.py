@@ -79,7 +79,7 @@ def test_r1_ignores_small_unaliased_donations():
 # ------------------------------------------------------------------ R2
 
 def test_r2_fires_on_f64_outside_sanctioned_files():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(lambda x: x.astype(jnp.float64) * 2.0)(
             jnp.ones(4, jnp.float32))
     out = jaxpr_lint.lint_dtypes(closed, program="fixture")
@@ -88,7 +88,7 @@ def test_r2_fires_on_f64_outside_sanctioned_files():
 
 
 def test_r2_sanctioned_file_is_exempt():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(lambda x: x.astype(jnp.float64) * 2.0)(
             jnp.ones(4, jnp.float32))
     # this test file is the emitting user frame; sanction it

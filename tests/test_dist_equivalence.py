@@ -27,6 +27,7 @@ from repro.core.topology import GossipPlan, circulant_row, make_topology
 from repro.core.triggers import constant, zero
 from repro.dist import sharding as sh
 from repro.dist.sparq_dist import DistSparqConfig, build_sparq
+from repro.launch.mesh import make_mesh
 from repro.models.transformer import init_params, lm_loss
 
 N = 4   # decentralized nodes (replicated on this 1-device mesh)
@@ -37,7 +38,7 @@ def _setup():
     cfg = dataclasses.replace(
         get_config("qwen1.5-0.5b").reduced(n_layers=1, d_model=128, vocab=256),
         n_nodes=N)
-    prod = jax.make_mesh((1, 1), ("data", "model"))
+    prod = make_mesh((1, 1), ("data", "model"))
     mesh = sh.train_mesh(prod, cfg)
     rng = np.random.default_rng(0)
     batch = {k: jnp.asarray(

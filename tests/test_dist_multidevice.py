@@ -20,9 +20,10 @@ SCRIPT = textwrap.dedent("""
     from repro.dist import sharding as sh
     from repro.dist.sparq_dist import DistSparqConfig, build_sparq
     from repro.core.topology import make_topology
+    from repro.launch.mesh import make_mesh
 
     cfg = dataclasses.replace(get_config("qwen1.5-0.5b").reduced(), n_nodes=4)
-    prod = jax.make_mesh((4, 2), ("data", "model"))
+    prod = make_mesh((4, 2), ("data", "model"))
     mesh = sh.train_mesh(prod, cfg)
 
     def setup(variant, frac=1.0, H=2, steps=6, kernel=False):

@@ -33,8 +33,9 @@ FIXTURE_SCRIPT = textwrap.dedent("""
     import json
     import jax, jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import make_mesh
 
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
 
     def ns(*spec):
         return NamedSharding(mesh, P(*spec))

@@ -21,6 +21,7 @@ from repro.core.schedule import fixed
 from repro.core.triggers import zero
 from repro.dist import sharding as sh
 from repro.dist.sparq_dist import DistSparqConfig, build_sparq
+from repro.launch.mesh import make_mesh
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -29,7 +30,7 @@ def _engine():
     cfg = dataclasses.replace(
         get_config("qwen1.5-0.5b").reduced(n_layers=1, d_model=128, vocab=256),
         n_nodes=4)
-    prod = jax.make_mesh((1, 1), ("data", "model"))
+    prod = make_mesh((1, 1), ("data", "model"))
     mesh = sh.train_mesh(prod, cfg)
     # momentum > 0 so the opt subtree carries real (non-empty) buffers —
     # exactly the state the old params-only checkpoint lost
