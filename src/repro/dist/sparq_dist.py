@@ -283,9 +283,10 @@ def build_sparq(cfg, mesh, dcfg: DistSparqConfig
 
     def unravel(flat: jax.Array):
         """One node row (D_pad,) or (D,) -> model pytree (static slices)."""
-        return jax.tree.unflatten(treedef, [
-            flat[off:off + size].reshape(leaf.shape).astype(leaf.dtype)
-            for off, size, leaf in slices])
+        with jax.named_scope("sparq_unravel"):
+            return jax.tree.unflatten(treedef, [
+                flat[off:off + size].reshape(leaf.shape).astype(leaf.dtype)
+                for off, size, leaf in slices])
 
     def ravel(tree) -> jax.Array:
         """Model pytree -> (D,) f32 flat vector (leaf order of pshape)."""
@@ -381,19 +382,28 @@ def build_sparq(cfg, mesh, dcfg: DistSparqConfig
                 f"batch leading dims {sorted(lead)} != ensemble size {n} "
                 f"(cfg.n_nodes={cfg.n_nodes} stretched over a node axis of "
                 f"{node_ax}; see build_sparq.__doc__)")
-        losses, grads = node_losses_grads(state["params"], batch)
-        loss = jnp.mean(losses)
+        # Each layer of the step runs under a named scope, which the HLO
+        # carries as op_name metadata and the profiler trace as each op's
+        # tf_op: sparq_grad (with sparq_unravel inside), sparq_optimizer,
+        # and in the sync branch sparq_trigger, sparq_compress, sparq_mix.
+        # No scope may name sign_topk or pallas (analysis/comm_lint.py's
+        # interpret markers would hide a gossip collective under it).
+        with jax.named_scope("sparq_grad"):
+            losses, grads = node_losses_grads(state["params"], batch)
+            loss = jnp.mean(losses)
         eta = dcfg.lr(state["t"]).astype(jnp.float32)
-        # local update through the shared optimizer seam (optim/sgd.py):
-        # plain SGD by default, heavyball/Nesterov for SQuARM-SGD
-        x_half, opt_new = opt.update(grads, state["opt"], state["params"], eta)
-        if flt is not None:
-            # stragglers / offline nodes skip this local step: iterate AND
-            # optimizer buffers freeze (same step_mask stream as the
-            # reference engine — core/faults.py determinism contract)
-            act = flt.step_mask(state["t"], n)                   # (n,) bool
-            x_half = flt.gate_update(act, x_half, state["params"])
-            opt_new = flt.gate_update(act, opt_new, state["opt"])
+        with jax.named_scope("sparq_optimizer"):
+            # local update through the shared optimizer seam (optim/sgd.py):
+            # plain SGD by default, heavyball/Nesterov for SQuARM-SGD
+            x_half, opt_new = opt.update(grads, state["opt"], state["params"],
+                                         eta)
+            if flt is not None:
+                # stragglers / offline nodes skip this local step: iterate
+                # AND optimizer buffers freeze (same step_mask stream as the
+                # reference engine — core/faults.py determinism contract)
+                act = flt.step_mask(state["t"], n)               # (n,) bool
+                x_half = flt.gate_update(act, x_half, state["params"])
+                opt_new = flt.gate_update(act, opt_new, state["opt"])
 
         def sync_branch(op):
             xh, xe = op                       # (n, D_pad) f32 / xhat_dt
@@ -404,41 +414,45 @@ def build_sparq(cfg, mesh, dcfg: DistSparqConfig
             else:
                 r = jax.lax.rem(state["sync_rounds"], jnp.int32(R))
                 W_r, deg_r = Ws[r], degs[r]
-            c_t = dcfg.threshold(state["t"])
-            diff = xh.astype(jnp.float32) - xe.astype(jnp.float32)
-            trig = trigger_mask(jnp.sum(diff * diff, axis=1), c_t, eta)
-            if flt is not None:
-                # faulty round: repaired W over the surviving links, offline
-                # nodes muted, bits charged for live links only
-                W_r, deg_r, live = flt.apply(W_r, state["t"],
-                                             state["sync_rounds"])
-                trig = trig & live
-            trigf = trig.astype(jnp.float32)
+            with jax.named_scope("sparq_trigger"):
+                c_t = dcfg.threshold(state["t"])
+                diff = xh.astype(jnp.float32) - xe.astype(jnp.float32)
+                trig = trigger_mask(jnp.sum(diff * diff, axis=1), c_t, eta)
+                if flt is not None:
+                    # faulty round: repaired W over the surviving links,
+                    # offline nodes muted, bits charged for live links only
+                    W_r, deg_r, live = flt.apply(W_r, state["t"],
+                                                 state["sync_rounds"])
+                    trig = trig & live
+                trigf = trig.astype(jnp.float32)
 
-            if dcfg.use_kernel:
-                # ONE fused blockwise dispatch per device over its node rows
-                # (kernels/ops.py; == vmapping BlockTopFrac row-by-row).
-                # Trigger gating happens below: q is linear in the 0/1 gate.
-                q = kernel_rows(diff)
-            else:
-                # generic registry operator over the TRUE flat vector (n, D)
-                # rows — one global operator application per node, matching
-                # the reference engine's (n, d) semantics exactly; per-node
-                # keys folded from the step counter (deterministic operators
-                # ignore them)
-                kc = jax.random.fold_in(base_key, state["t"])
-                q_d = jax.vmap(lambda v, k: comp(v, k))(
-                    diff[:, :D], jax.random.split(kc, n))
-                q = jnp.pad(q_d, ((0, 0), (0, D_pad - D)))
-            q = q * trigf[:, None]                               # line 11
-            xe_new = (xe.astype(jnp.float32) + q).astype(xhat_dt)  # line 13
-            x_new = xh + gamma * mix_term(xe_new, W_r)           # line 15
-            new_bits, new_c = bits_mod.acc_add(
-                state["bits"], state["bits_c"],
-                sync_message_bits(trig, deg_r, payload))
-            return (x_new, xe_new, new_bits, new_c,
-                    state["sync_rounds"] + 1,
-                    state["triggers"] + jnp.sum(trig).astype(jnp.int32))
+            with jax.named_scope("sparq_compress"):
+                if dcfg.use_kernel:
+                    # ONE fused blockwise dispatch per device over its node
+                    # rows (kernels/ops.py; == vmapping BlockTopFrac
+                    # row-by-row). Trigger gating happens below: q is linear
+                    # in the 0/1 gate.
+                    q = kernel_rows(diff)
+                else:
+                    # generic registry operator over the TRUE flat vector
+                    # (n, D) rows — one global operator application per
+                    # node, matching the reference engine's (n, d) semantics
+                    # exactly; per-node keys folded from the step counter
+                    # (deterministic operators ignore them)
+                    kc = jax.random.fold_in(base_key, state["t"])
+                    q_d = jax.vmap(lambda v, k: comp(v, k))(
+                        diff[:, :D], jax.random.split(kc, n))
+                    q = jnp.pad(q_d, ((0, 0), (0, D_pad - D)))
+                q = q * trigf[:, None]                           # line 11
+            with jax.named_scope("sparq_mix"):
+                xe_new = (xe.astype(jnp.float32) + q).astype(xhat_dt)  # 13
+                x_new = xh + gamma * mix_term(xe_new, W_r)       # line 15
+                new_bits, new_c = bits_mod.acc_add(
+                    state["bits"], state["bits_c"],
+                    sync_message_bits(trig, deg_r, payload))
+                rounds = state["sync_rounds"] + 1
+                triggers = state["triggers"] + jnp.sum(trig).astype(jnp.int32)
+            return x_new, xe_new, new_bits, new_c, rounds, triggers
 
         def local_branch(op):
             xh, xe = op

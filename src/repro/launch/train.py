@@ -104,7 +104,29 @@ def parse_args(argv: Optional[Sequence[str]] = None) -> argparse.Namespace:
     ap.add_argument("--resume", action="store_true",
                     help="continue from the latest checkpoint in --ckpt-dir "
                          "(full train state: params, x_hat, opt, t, bits)")
+    ap.add_argument("--profile-dir", default="",
+                    help="write a jax.profiler trace of --profile-steps "
+                         "under this directory")
+    ap.add_argument("--profile-steps", default="", metavar="A:B",
+                    help="trace steps A to B-1 (with --profile-dir)")
     return ap.parse_args(argv)
+
+
+def profile_range(args: argparse.Namespace) -> Optional[range]:
+    """The steps ``--profile-dir``/``--profile-steps A:B`` trace, or None
+    when neither is given."""
+    if not args.profile_dir and not args.profile_steps:
+        return None
+    try:
+        a, b = (int(p) for p in args.profile_steps.split(":"))
+    except ValueError:
+        a = b = -1
+    if not args.profile_dir or not 0 <= a < b:
+        raise SystemExit(
+            f"[train] profiling needs --profile-dir and --profile-steps A:B "
+            f"with 0 <= A < B, got {args.profile_dir!r} and "
+            f"{args.profile_steps!r}")
+    return range(a, b)
 
 
 def setup_compile_cache() -> None:
@@ -279,24 +301,57 @@ def run(args: argparse.Namespace,
         print("[train] --lint: compiled step passes the static audit "
               "(lowering + theory contracts)")
 
+    # Host spans on the profiler's clock, named as the benchmark's harness
+    # names them; a TraceAnnotation with no trace running costs well under
+    # a microsecond, so they stay in the loop whether or not it is traced.
+    ann = jax.profiler.TraceAnnotation
+    profiled = profile_range(args)
+    tracing = False
     metrics = None
     t_first = None
-    for i in range(start, args.steps):
-        batch = jax.device_put(pipe.global_batch(i), bsh)
-        state, metrics = compiled(state, batch)
-        if t_first is None:
-            # steady timing starts once the first step has finished
+    try:
+        for i in range(start, args.steps):
+            if profiled is not None and i == profiled.start:
+                jax.profiler.start_trace(args.profile_dir)
+                tracing = True
+            last_traced = tracing and i + 1 == profiled.stop
+            with jax.profiler.StepTraceAnnotation("train", step_num=i):
+                with ann("batch"):
+                    rows = pipe.global_batch(i)
+                with ann("device_put"):
+                    batch = jax.device_put(rows, bsh)
+                with ann("dispatch"):
+                    state, metrics = compiled(state, batch)
+                if t_first is None:
+                    # steady timing starts once the first step has finished
+                    with ann("wait"):
+                        jax.block_until_ready(metrics)
+                    t_first = time.perf_counter()
+                if (i + 1) % args.log_every == 0:
+                    with ann("log"):
+                        m = {k: float(v) for k, v in metrics.items()}
+                        print(f"[train] step {i+1:5d} loss {m['loss']:.4f} "
+                              f"eta {m['eta']:.4f} bits {m['bits']:.3e} "
+                              f"triggers {m['triggers']:.0f}")
+                if args.ckpt_dir and args.ckpt_every and \
+                        (i + 1) % args.ckpt_every == 0:
+                    with ann("checkpoint"):
+                        path = ckpt.save(args.ckpt_dir, i + 1,
+                                         jax.device_get(state))
+                    print(f"[train] checkpoint -> {path}")
+                if last_traced:
+                    # the traced steps' device work ends inside the trace
+                    with ann("wait"):
+                        jax.block_until_ready(metrics)
+            if last_traced:
+                jax.profiler.stop_trace()
+                tracing = False
+                print(f"[train] profile of steps {profiled.start} to "
+                      f"{profiled.stop - 1} -> {args.profile_dir}")
+    finally:
+        if tracing:
             jax.block_until_ready(metrics)
-            t_first = time.perf_counter()
-        if (i + 1) % args.log_every == 0:
-            m = {k: float(v) for k, v in metrics.items()}
-            print(f"[train] step {i+1:5d} loss {m['loss']:.4f} "
-                  f"eta {m['eta']:.4f} bits {m['bits']:.3e} "
-                  f"triggers {m['triggers']:.0f}")
-        if args.ckpt_dir and args.ckpt_every and \
-                (i + 1) % args.ckpt_every == 0:
-            path = ckpt.save(args.ckpt_dir, i + 1, jax.device_get(state))
-            print(f"[train] checkpoint -> {path}")
+            jax.profiler.stop_trace()
     if metrics is None:
         # no steps ran (steps <= start, e.g. --steps 0 or an already-complete
         # resume): there is no final metrics dict to report

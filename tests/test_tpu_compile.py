@@ -22,7 +22,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.ops import sign_topk_ensemble
 from repro.kernels.qsgd import qsgd_blocks
-from repro.kernels.sign_topk import BLOCK, sign_topk_blocks
+from repro.kernels.sign_topk import BLOCK, KERNEL_NAME, sign_topk_blocks
 
 QWEN_D = 619_570_176            # qwen1.5-0.5b raveled, = 605,049 * BLOCK
 QWEN_TILES = 605_056            # sign_topk_ensemble's padded tile count
@@ -71,3 +71,18 @@ def test_qsgd_blocks_compiles_for_v5e(one_chip):
     x = _sds((64, BLOCK), one_chip)
     _assert_mosaic(qsgd_blocks.lower(x, x, s=16,
                                      lowering="pallas").compile())
+
+
+def test_sign_topk_custom_call_carries_the_kernel_name(one_chip):
+    """The Pallas call's fixed name is the HLO custom call's (XLA may add a
+    ``.N`` suffix), whatever jit wraps it; it starts with ``sign_topk``,
+    which the benchmark's kernel reader matches."""
+    import re
+    assert KERNEL_NAME.startswith("sign_topk")
+    text = sign_topk_ensemble.lower(
+        _sds((2, 64 * BLOCK), one_chip), K_B,
+        lowering="pallas").compile().as_text()
+    calls = re.findall(r"%([\w.-]+) = .*custom-call\(.*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert calls and all(re.fullmatch(re.escape(KERNEL_NAME) + r"(\.\d+)?",
+                                      c) for c in calls), calls

@@ -56,3 +56,58 @@ def test_compile_cache_follows_env_var(tmp_path):
     assert any(cache.iterdir())
     assert (_cache_dir_after_run(tmp_path, None)
             == os.path.join(ROOT, ".jax_cache"))
+
+
+def _host_spans(log_dir):
+    """The host planes' ``train`` step spans of the trace under log_dir, as
+    their step numbers, and the names of all host spans."""
+    import glob
+    from jax.profiler import ProfileData
+    found = glob.glob(os.path.join(str(log_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(found) == 1, found
+    steps, names = [], set()
+    for plane in ProfileData.from_file(found[0]).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                names.add(e.name)
+                if e.name == "train":
+                    steps.append(int(dict(e.stats)["step_num"]))
+    return sorted(steps), names
+
+
+def test_profile_steps_trace_the_named_steps(tmp_path):
+    out = tmp_path / "trace"
+    res = train.run(train.parse_args([
+        "--reduced", "--nodes", "1", "--steps", "4", "--H", "2",
+        "--seq-len", "16", "--batch-per-node", "1",
+        "--profile-dir", str(out), "--profile-steps", "1:3"]))
+    assert res.metrics is not None
+    steps, names = _host_spans(out)
+    assert steps == [1, 2]
+    assert {"batch", "device_put", "dispatch", "wait"} <= names
+
+
+def test_without_profile_steps_no_trace_starts(monkeypatch, tmp_path):
+    import jax
+    calls = []
+    monkeypatch.setattr(jax.profiler, "start_trace",
+                        lambda *a, **k: calls.append(a))
+    monkeypatch.chdir(tmp_path)
+    res = train.run(train.parse_args([
+        "--reduced", "--nodes", "1", "--steps", "2", "--H", "2",
+        "--seq-len", "16", "--batch-per-node", "1"]))
+    assert res.metrics is not None
+    assert calls == [] and list(tmp_path.iterdir()) == []
+
+
+def test_profile_steps_need_a_directory_and_a_range():
+    import pytest
+    for argv in (["--profile-steps", "1:3"], ["--profile-dir", "d"],
+                 ["--profile-dir", "d", "--profile-steps", "3:1"],
+                 ["--profile-dir", "d", "--profile-steps", "x"]):
+        with pytest.raises(SystemExit):
+            train.profile_range(train.parse_args(argv))
+    assert train.profile_range(train.parse_args([])) is None
