@@ -18,9 +18,11 @@ lane). |support| <= k_b always, so a (vals, idx) payload of k_b entries per
 tile reconstructs q exactly, ties included.
 
 Layout: the flat parameter shard is padded and reshaped to (n_blocks, BLOCK)
-with BLOCK = 1024 = 8 sublanes x 128 lanes; BlockSpec tiles one (block_rows,
-BLOCK) slab per grid step so the VMEM working set is block_rows x 4KiB x 3
-buffers, well under the ~16 MiB v5e VMEM budget.
+with BLOCK = 1024 = 8 sublanes x 128 lanes; BlockSpec tiles one tall
+(rows, BLOCK) slab of up to BLOCK_ROWS tiles per grid step. Each selection
+pass is a compare plus a cross-lane row count; the count's latency on the
+XLU is what a pass waits for, so a slab of many independent 8-row groups
+lets the scheduler issue the other groups' passes meanwhile.
 
 GPU-vs-TPU note (DESIGN §3): the reference CUDA Top-k is a global radix select;
 here selection is per 1024-element tile (same total k) — no cross-tile traffic,
@@ -38,33 +40,42 @@ from jax.experimental import pallas as pl
 from repro.kernels import resolve_lowering
 
 BLOCK = 1024
-BLOCK_ROWS = 8  # tiles per grid step: VMEM slab = 8 x 1024 x 4B x 3 = 96 KiB
+# tiles per grid step, the fastest of 8..256 on a TPU v5e (PERF.md); a
+# multiple of 8 sublanes. 512 tiles overflow Mosaic's 16 MiB scoped VMEM.
+BLOCK_ROWS = 256
 # the Pallas call's fixed name, which the HLO custom call and the profiler
 # trace carry; it starts with sign_topk, which the benchmark's kernel reader
 # matches
 KERNEL_NAME = "sign_topk_tiles"
 
 
+def _row_count(mask: jax.Array) -> jax.Array:
+    """Per-row count of a (rows, B) bool mask as (rows, 1) float32: exact up
+    to 2**24, and one cross-lane reduce where an int32 sum takes two."""
+    return jnp.sum(mask.astype(jnp.float32), axis=1, keepdims=True)
+
+
 def _row_threshold(av: jax.Array, k_b: int) -> jax.Array:
     """Per-row k_b-th largest of nonnegative f32 rows, by EXACT radix select
-    on the float bit patterns (for av >= 0 the uint32 pattern order equals
-    numeric order). 32 compare+count passes instead of a full sort — on CPU
-    XLA this is ~20x faster than ``lax.sort`` at (64, 1024), and the passes
-    are plain elementwise-compare + row-sum, VPU-friendly under Mosaic where
+    on the float bit patterns (for av >= 0 the int32 pattern order equals
+    numeric order, and bit 31 is clear, so the select starts at bit 30).
+    31 compare+count passes instead of a full sort — on CPU XLA this is ~20x
+    faster than ``lax.sort`` at (64, 1024), and the passes are plain
+    elementwise-compare + row-sum, VPU-friendly under Mosaic where
     ``lax.sort`` has no lowering at all. The returned value is an achieved
-    element (the largest t with count(av >= t) >= k_b), so it is bit-equal
-    to ``sort(av)[..., -k_b]`` — every lowering leg shares this function and
-    therefore the exact same threshold floats. av: (rows, B) -> (rows, 1)."""
-    u = jax.lax.bitcast_convert_type(av, jnp.uint32)
+    element (the largest t with count(av >= t) >= k_b >= 1), so it is
+    bit-equal to ``sort(av)[..., -k_b]`` — every lowering leg shares this
+    function and therefore the exact same threshold floats.
+    av: (rows, B) -> (rows, 1)."""
+    u = jax.lax.bitcast_convert_type(av, jnp.int32)
 
     def body(i, prefix):
-        cand = prefix | (jnp.uint32(1) << jnp.uint32(31 - i))
-        cnt = jnp.sum((u >= cand[:, None]).astype(jnp.int32), axis=1)
-        return jnp.where(cnt >= k_b, cand, prefix)
+        cand = prefix | jnp.left_shift(jnp.int32(1), 30 - i)
+        return jnp.where(_row_count(u >= cand) >= k_b, cand, prefix)
 
-    bits = jax.lax.fori_loop(0, 32, body,
-                             jnp.zeros((av.shape[0],), jnp.uint32))
-    return jax.lax.bitcast_convert_type(bits, jnp.float32)[:, None]
+    bits = jax.lax.fori_loop(0, 31, body,
+                             jnp.zeros((av.shape[0], 1), jnp.int32))
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
 
 
 def _first_ties(tie: jax.Array, quota: jax.Array) -> jax.Array:
@@ -74,14 +85,15 @@ def _first_ties(tie: jax.Array, quota: jax.Array) -> jax.Array:
     cutoff m in [0, BLOCK] with count(tie[:, :m]) <= quota by compare +
     row-sum passes, and the prefix count is nondecreasing, so the lanes
     below m are the ones whose rank is within quota.
-    tie: (rows, B) bool; quota: (rows, 1) int32 >= 0."""
+    tie: (rows, B) bool; quota: (rows, 1) float32, a whole number >= 0."""
     lane = jax.lax.broadcasted_iota(jnp.int32, tie.shape, 1)
-    tie_i = tie.astype(jnp.int32)
+    tie_f = tie.astype(jnp.float32)
     top = tie.shape[1].bit_length() - 1
 
     def body(i, m):
         cand = m | jnp.left_shift(jnp.int32(1), top - i)
-        cnt = jnp.sum(jnp.where(lane < cand, tie_i, 0), axis=1,
+        # a select on the f32 mask: fewer VPU ops a pass than and + convert
+        cnt = jnp.sum(jnp.where(lane < cand, tie_f, 0.0), axis=1,
                       keepdims=True)
         return jnp.where((cand <= tie.shape[1]) & (cnt <= quota), cand, m)
 
@@ -108,9 +120,9 @@ def _block_compress(diff: jax.Array, trig: jax.Array, k_b: int
     tie = jnp.logical_and(jnp.logical_and(av >= thr,
                                           jnp.logical_not(gt)), pos)
     # fill the remaining quota with the LOWEST-index ties (top_k order)
-    quota = k_b - jnp.sum(gt.astype(jnp.int32), axis=1, keepdims=True)
+    quota = k_b - _row_count(gt)
     mask = jnp.logical_or(gt, _first_ties(tie, quota))
-    nsel = jnp.sum(mask.astype(jnp.float32), axis=1, keepdims=True)
+    nsel = _row_count(mask)
     scale = (jnp.sum(jnp.where(mask, av, 0.0), axis=1, keepdims=True)
              / jnp.maximum(nsel, 1.0))
     signs = jnp.where(diff >= 0, 1.0, -1.0)
@@ -144,6 +156,18 @@ def _sign_topk_xla(x_half: jax.Array, x_hat: jax.Array, trig: jax.Array,
     return q, x_hat + q, scale[:, 0]
 
 
+def slab_rows(n: int) -> int:
+    """Tiles per grid step for n tiles: all n where they fit one slab, else
+    the tallest slab of at most BLOCK_ROWS tiles, a multiple of 8 sublanes,
+    that divides n (every tile row is computed alone, so the height changes
+    no result)."""
+    if n <= BLOCK_ROWS:
+        return n
+    rows = next((r for r in range(BLOCK_ROWS, 0, -8) if n % r == 0), 0)
+    assert rows, f"{n} tiles: no multiple of 8 up to {BLOCK_ROWS} divides it"
+    return rows
+
+
 @functools.partial(jax.jit, static_argnames=("k_b", "interpret", "lowering"))
 def sign_topk_blocks(x_half: jax.Array, x_hat: jax.Array, trig: jax.Array,
                      k_b: int, interpret: Optional[bool] = None,
@@ -159,8 +183,7 @@ def sign_topk_blocks(x_half: jax.Array, x_hat: jax.Array, trig: jax.Array,
     trig_arr = jnp.asarray(trig, jnp.float32)
     if lw == "xla":
         return _sign_topk_xla(x_half, x_hat, trig_arr, k_b)
-    rows = min(BLOCK_ROWS, n)
-    assert n % rows == 0
+    rows = slab_rows(n)
     grid = (n // rows,)
     q, xe_new, scale = pl.pallas_call(
         functools.partial(_sign_topk_kernel, k_b=k_b),

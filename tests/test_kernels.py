@@ -9,7 +9,12 @@ from hypothesis_compat import given, settings, st
 
 from repro.kernels import ops, ref
 from repro.kernels.qsgd import qsgd_blocks
-from repro.kernels.sign_topk import BLOCK, sign_topk_blocks
+from repro.kernels.sign_topk import (
+    BLOCK,
+    BLOCK_ROWS,
+    sign_topk_blocks,
+    slab_rows,
+)
 
 
 @pytest.mark.parametrize("nb", [1, 2, 8, 16, 32])
@@ -153,24 +158,71 @@ def test_xhat_update_closes_the_loop():
 LEGS = ("interpret", "xla")
 
 
+def _mixed_tiles(key, n, dtype):
+    """(n, BLOCK) x_half and x_hat whose differences hold, besides random
+    tiles, tiles of one |diff| (every lane tied), all-zero tiles and tiles
+    with fewer than 102 nonzero lanes."""
+    xh = jax.random.normal(key, (n, BLOCK), dtype)
+    xe = 0.3 * jax.random.normal(jax.random.fold_in(key, 1), (n, BLOCK),
+                                 dtype)
+    row = jnp.arange(n)[:, None]
+    lane = jnp.arange(BLOCK)[None, :]
+    tied = jnp.where(lane % 3 == 0, 1.5, -1.5).astype(dtype)
+    xh = jnp.where(row % 5 == 1, tied, xh)
+    xe = jnp.where((row % 5 == 1) | (row % 5 == 2), 0, xe)
+    xh = jnp.where(row % 5 == 2, jnp.where(lane % 16 == 0, xh, 0), xh)
+    xe = jnp.where(row % 5 == 3, xh, xe)
+    return xh, xe
+
+
+@pytest.mark.parametrize("n_tiles", [8, 13, 2 * BLOCK_ROWS,
+                                     2 * BLOCK_ROWS + 8])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_sign_topk_legs_bit_equal_to_oracle(dtype):
+def test_sign_topk_legs_bit_equal_to_oracle(dtype, n_tiles):
     """The compiled XLA leg and the Pallas interpreter run the IDENTICAL
     per-row f32 block math, so all three (interpret, xla, ref.py) must be
-    BIT-equal — not close — for q, x_hat_new and the scales, f32 and bf16."""
-    key = jax.random.PRNGKey(11)
-    xh = jax.random.normal(key, (8, BLOCK), dtype)
-    xe = 0.3 * jax.random.normal(jax.random.fold_in(key, 1), (8, BLOCK), dtype)
+    BIT-equal — not close — for q, x_hat_new and the scales, f32 and bf16,
+    in one slab (8, 13 tiles), in whole slabs of BLOCK_ROWS, and in shorter
+    slabs where BLOCK_ROWS does not divide the tile count; with tied,
+    all-zero and sparse tiles among the random ones."""
+    xh, xe = _mixed_tiles(jax.random.PRNGKey(11), n_tiles, dtype)
     q_r, xn_r, _, _ = ref.sign_topk_ref(xh.reshape(-1), xe.reshape(-1),
                                         jnp.float32(1.0), 102)
+    q_r = np.asarray(q_r.astype(dtype)).reshape(n_tiles, BLOCK)
+    # trig = 1: every selected lane of q is +-scale, so a row's scale is its
+    # largest |q| (0 where nothing is selected)
+    sc_r = np.abs(q_r.astype(np.float32)).max(axis=1)
     for leg in LEGS:
         q, xn, sc = sign_topk_blocks(xh, xe, jnp.float32(1.0), 102,
                                      lowering=leg)
-        np.testing.assert_array_equal(np.asarray(q.reshape(-1)),
-                                      np.asarray(q_r.astype(dtype)))
+        np.testing.assert_array_equal(np.asarray(q), q_r)
         np.testing.assert_array_equal(np.asarray(xn.reshape(-1)),
                                       np.asarray(xn_r.astype(dtype)))
         assert sc.dtype == jnp.float32
+        np.testing.assert_array_equal(
+            np.asarray(sc.astype(dtype)).astype(np.float32), sc_r)
+    # the zero, tied and sparse tiles took their paths: nothing selected,
+    # exactly 102 lowest-index ties, all 64 nonzero lanes
+    nnz = (q_r != 0).sum(axis=1)
+    assert (nnz[3::5] == 0).all()
+    assert (nnz[1::5] == 102).all()
+    assert (np.flatnonzero(q_r[1]) == np.arange(102)).all()
+    assert (nnz[2::5] == BLOCK // 16).all()
+
+
+@pytest.mark.parametrize("n_tiles", [1, 13, BLOCK_ROWS, 2 * BLOCK_ROWS,
+                                     2 * BLOCK_ROWS + 8, 4727 * 128])
+def test_slab_rows_divides_the_tile_count(n_tiles):
+    """One slab holds all tiles up to BLOCK_ROWS; above, the slab is the
+    tallest multiple of 8 up to BLOCK_ROWS that divides the count."""
+    rows = slab_rows(n_tiles)
+    assert n_tiles % rows == 0
+    if n_tiles <= BLOCK_ROWS:
+        assert rows == n_tiles
+    else:
+        assert rows % 8 == 0 and rows <= BLOCK_ROWS
+        assert not any(n_tiles % r == 0
+                       for r in range(rows + 8, BLOCK_ROWS + 1, 8))
 
 
 def test_qsgd_legs_bit_equal_to_oracle():
@@ -272,10 +324,13 @@ def test_exact_k_support_matches_top_k():
         assert got == set(np.asarray(want_idx[r]).tolist())
 
 
-def test_ensemble_matches_per_row_wrapper():
+@pytest.mark.parametrize("n, d", [(4, 2 * BLOCK + 300),
+                                  (2, (BLOCK_ROWS // 2) * BLOCK + 300)])
+def test_ensemble_matches_per_row_wrapper(n, d):
     """sign_topk_ensemble (ONE dispatch over all nodes' tiles) must be
-    bit-equal to running trigger_compress_update row by row."""
-    n, d = 4, 2 * BLOCK + 300
+    bit-equal to running trigger_compress_update row by row: in one slab,
+    and where the stacked tiles pass one slab and each node's row is
+    padded until they fill whole slabs."""
     diff = jax.random.normal(jax.random.PRNGKey(9), (n, d))
     for leg in LEGS:
         q_ens = ops.sign_topk_ensemble(diff, 13, lowering=leg)

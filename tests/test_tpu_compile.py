@@ -4,9 +4,11 @@ Nothing runs here: each test compiles a kernel's ``pallas`` leg for one chip
 of a described ``v5e:2x2`` topology (the TPU compiler is installed with
 jaxlib) and checks that Mosaic emitted a ``tpu_custom_call``. This catches
 what interpret mode cannot — block shapes Mosaic refuses, primitives it has
-no lowering for — at the real flat size of qwen1.5-0.5b
-(D = 619,570,176 = 605,049 tiles of 1024, which the train step's kernel call
-pads to 605,056 tiles, a whole number of 8-tile slabs).
+no lowering for, a slab that overflows the 16 MiB scoped VMEM — at the real
+flat sizes: qwen1.5-0.5b with its untied head (D = 619,570,176 = 605,049
+tiles of 1024, which the train step's kernel call pads to 605,184 tiles, a
+whole number of BLOCK_ROWS-tile slabs), and the benchmark's rows,
+qwen1.5-0.5b tied and mamba2-370m.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and every test worker imports
@@ -22,10 +24,17 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.ops import sign_topk_ensemble
 from repro.kernels.qsgd import qsgd_blocks
-from repro.kernels.sign_topk import BLOCK, KERNEL_NAME, sign_topk_blocks
+from repro.kernels.sign_topk import (
+    BLOCK,
+    BLOCK_ROWS,
+    KERNEL_NAME,
+    sign_topk_blocks,
+)
 
 QWEN_D = 619_570_176            # qwen1.5-0.5b raveled, = 605,049 * BLOCK
-QWEN_TILES = 605_056            # sign_topk_ensemble's padded tile count
+# sign_topk_ensemble's padded tile count: whole BLOCK_ROWS-tile slabs
+QWEN_TILES = -(-QWEN_D // (BLOCK * BLOCK_ROWS)) * BLOCK_ROWS
+BENCH_D = {"qwen1.5-0.5b-tied": 463_987_712, "mamba2-370m": 368_338_432}
 K_B = 103                       # ceil(0.1 * BLOCK)
 
 
@@ -55,16 +64,20 @@ def _assert_mosaic(compiled):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("n_tiles", [16, QWEN_TILES])
+# 605,056 = 128 * 4727 tiles: a count BLOCK_ROWS does not divide, run in
+# shorter slabs
+@pytest.mark.parametrize("n_tiles", [16, QWEN_TILES, 605_056])
 def test_sign_topk_blocks_compiles_for_v5e(one_chip, n_tiles):
     x = _sds((n_tiles, BLOCK), one_chip)
     _assert_mosaic(sign_topk_blocks.lower(
         x, x, _sds((), one_chip), K_B, lowering="pallas").compile())
 
 
-def test_sign_topk_ensemble_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("d", [QWEN_D, *BENCH_D.values()],
+                         ids=["qwen1.5-0.5b", *BENCH_D])
+def test_sign_topk_ensemble_compiles_for_v5e(one_chip, d):
     _assert_mosaic(sign_topk_ensemble.lower(
-        _sds((1, QWEN_D), one_chip), K_B, lowering="pallas").compile())
+        _sds((1, d), one_chip), K_B, lowering="pallas").compile())
 
 
 def test_qsgd_blocks_compiles_for_v5e(one_chip):
