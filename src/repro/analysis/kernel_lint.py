@@ -10,7 +10,9 @@ These rules make that class of drift a lint error before any TPU is involved:
 * **K1 pallas-grid-coverage** — every ``pallas_call`` in src/repro/kernels/
   is exercised by a registered probe under ``jax.eval_shape`` (abstract — no
   kernel executes) with ``pl.pallas_call`` monkey-patched to capture the
-  (grid, BlockSpecs, operand shapes, interpret flag) of each site. The
+  (grid, BlockSpecs, operand shapes, interpret flag) of each site, from its
+  keywords or its ``grid_spec``; a kernel whose index maps read
+  scalar-prefetch operands is probed with concrete values for them. The
   captured tiling must cover each operand exactly: index maps in bounds for
   every grid point, every element visited, and any padded tail (a dim not
   divisible by its block) masked in the kernel body (``pl.when``) — the
@@ -78,13 +80,14 @@ class PallasCapture:
 
     __slots__ = ("probe", "site", "kernel_src", "grid", "in_specs",
                  "out_specs", "operands", "outputs", "interpret",
-                 "scratch_bytes")
+                 "scratch_bytes", "prefetch")
 
     def __init__(self, probe: str, site: str, kernel_src: str,
                  grid: Tuple[int, ...], in_specs, out_specs,
                  operands: List[Tuple[Tuple[int, ...], str]],
                  outputs: List[Tuple[Tuple[int, ...], str]],
-                 interpret: Optional[bool], scratch_bytes: int) -> None:
+                 interpret: Optional[bool], scratch_bytes: int,
+                 prefetch: Sequence[Any] = ()) -> None:
         self.probe = probe
         self.site = site
         self.kernel_src = kernel_src
@@ -95,6 +98,9 @@ class PallasCapture:
         self.outputs = outputs
         self.interpret = interpret
         self.scratch_bytes = scratch_bytes
+        # scalar-prefetch operands, passed to every index map after the
+        # grid indices (None where the probe left one abstract)
+        self.prefetch = list(prefetch)
 
 
 def _kernel_site() -> str:
@@ -144,24 +150,34 @@ def capture_probes(probes: Sequence[Tuple[str, Callable, tuple, dict]]
         site = _kernel_site()
         ksrc = _kernel_source(kernel)
         inner = orig(kernel, *args, **kw)
+        gs = kw.get("grid_spec")
+        tiling = kw if gs is None else {
+            "grid": gs.grid, "in_specs": gs.in_specs,
+            "out_specs": gs.out_specs,
+            "scratch_shapes": getattr(gs, "scratch_shapes", ())}
+        n_pre = int(getattr(gs, "num_scalar_prefetch", 0) or 0)
 
         def applied(*operands):
             scratch = 0
-            for s in _spec_list(kw.get("scratch_shapes", ())):
+            for s in _spec_list(tiling.get("scratch_shapes", ())):
                 shp = getattr(s, "shape", None)
                 if shp is not None:
                     scratch += _nbytes(tuple(shp),
                                        getattr(s, "dtype", "float32"))
             outs = jax.tree.leaves(out_shape)
+            pre, rest = operands[:n_pre], operands[n_pre:]
             captures.append(PallasCapture(
                 probe=current[0], site=site, kernel_src=ksrc,
-                grid=tuple(int(g) for g in np.atleast_1d(kw.get("grid", ()))),
-                in_specs=_spec_list(kw.get("in_specs")),
-                out_specs=_spec_list(kw.get("out_specs")),
-                operands=[(tuple(o.shape), str(o.dtype)) for o in operands],
+                grid=tuple(int(g) for g in np.atleast_1d(
+                    tiling.get("grid", ()))),
+                in_specs=_spec_list(tiling.get("in_specs")),
+                out_specs=_spec_list(tiling.get("out_specs")),
+                operands=[(tuple(o.shape), str(o.dtype)) for o in rest],
                 outputs=[(tuple(o.shape), str(o.dtype)) for o in outs],
                 interpret=kw.get("interpret"),
-                scratch_bytes=scratch))
+                scratch_bytes=scratch,
+                prefetch=[o if isinstance(o, np.ndarray) else None
+                          for o in pre]))
             return inner(*operands)
 
         return applied
@@ -188,7 +204,7 @@ def default_probes() -> List[Tuple[str, Callable, tuple, dict]]:
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels import ops, qsgd, sign_topk
+    from repro.kernels import gmm, ops, qsgd, sign_topk
 
     B = sign_topk.BLOCK
 
@@ -196,6 +212,11 @@ def default_probes() -> List[Tuple[str, Callable, tuple, dict]]:
         return jax.ShapeDtypeStruct(shape, dtype)
 
     key = sds(2, dtype=jnp.uint32)
+    # the grouped products' index maps read the tile layout: four groups
+    # of one whole tile each, every tile active
+    tiles = {"tile_group": np.arange(4, dtype=np.int32),
+             "n_active": np.array([4], np.int32), "interpret": False}
+    rows = 4 * gmm.TILE_M
     return [
         ("sign_topk_blocks", sign_topk.sign_topk_blocks,
          (sds(8, B), sds(8, B), sds()), {"k_b": 102, "lowering": "pallas"}),
@@ -211,6 +232,19 @@ def default_probes() -> List[Tuple[str, Callable, tuple, dict]]:
          (sds(4, 2 * B + 300),), {"k_b": 13, "lowering": "pallas"}),
         ("ops.qsgd", ops.qsgd, (sds(5000), key),
          {"s": 16, "lowering": "pallas"}),
+        ("sign_topk_q", sign_topk.sign_topk_q, (sds(8, B),),
+         {"k_b": 102, "lowering": "pallas"}),
+        ("gmm", gmm._gmm_call,
+         (sds(rows, 64, dtype=jnp.bfloat16),
+          sds(4, 64, 96, dtype=jnp.bfloat16)),
+         dict(tiles, transpose_rhs=False)),
+        ("gmm/transposed", gmm._gmm_call,
+         (sds(rows, 96, dtype=jnp.bfloat16),
+          sds(4, 64, 96, dtype=jnp.bfloat16)),
+         dict(tiles, transpose_rhs=True)),
+        ("gmm/tgmm", gmm._tgmm_call,
+         (sds(rows, 64, dtype=jnp.bfloat16),
+          sds(rows, 96, dtype=jnp.bfloat16)), dict(tiles, groups=4)),
     ]
 
 
@@ -254,9 +288,14 @@ def lint_coverage(captures: Sequence[PallasCapture], *, program: str
         except ValueError as e:
             out.append(finding("K1", str(e), loc))
             continue
+        if any(v is None for v in cap.prefetch):
+            out.append(finding(
+                "K1", "scalar-prefetch operands left abstract: the probe "
+                      "must give their values for the index maps", loc))
+            continue
         for (shape, _dt), spec in pairs:
             meta["operands_checked"] += 1
-            bs = tuple(spec.block_shape)
+            bs = _block(spec)
             if len(bs) != len(shape):
                 out.append(finding(
                     "K1", f"block shape {bs} rank != operand rank of "
@@ -271,7 +310,7 @@ def lint_coverage(captures: Sequence[PallasCapture], *, program: str
             covered = np.zeros(shape, dtype=bool)
             oob = False
             for p in pts:
-                coord = spec.index_map(*p)
+                coord = spec.index_map(*p, *cap.prefetch)
                 coord = tuple(int(c) for c in np.atleast_1d(coord))
                 if len(coord) != len(bs):
                     out.append(finding(
@@ -438,13 +477,18 @@ def lint_interpret_budget(captures: Sequence[PallasCapture], *, program: str,
 
 # ----------------------------------------------------------------------- K3
 
+def _block(spec) -> Tuple[int, ...]:
+    """A BlockSpec's block shape, a squeezed (None) dim counted as 1."""
+    return tuple(1 if b is None else int(b) for b in spec.block_shape)
+
+
 def vmem_estimate(cap: PallasCapture) -> int:
     """Closed-form per-invocation VMEM bytes: one input tile + one output
     tile per spec, x2 for the double-buffered pipeline, + scratch."""
     tile = 0
     for (shape, dt), spec in (list(zip(cap.operands, cap.in_specs))
                               + list(zip(cap.outputs, cap.out_specs))):
-        bs = tuple(spec.block_shape)
+        bs = _block(spec)
         if len(bs) == len(shape):
             tile += _nbytes(bs, dt)
     return 2 * tile + cap.scratch_bytes

@@ -10,7 +10,7 @@ CONFIG = ModelConfig(
     n_layers=28, d_model=2048, n_heads=16, n_kv_heads=16,
     d_ff=10944, vocab_size=102400,
     n_experts=64, n_shared_experts=2, moe_top_k=6, moe_d_ff=1408,
-    first_k_dense=1,
+    first_k_dense=1, norm_topk_prob=False,   # published: no renormalization
     n_nodes=8,
     citation="arXiv:2401.06066",
 )

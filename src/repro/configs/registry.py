@@ -14,6 +14,7 @@ _MODULES = {
     "minitron-4b": "minitron_4b",
     "deepseek-moe-16b": "deepseek_moe_16b",
     "deepseek-v3-671b": "deepseek_v3_671b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
     "zamba2-7b": "zamba2_7b",
     "stablelm-1.6b": "stablelm_1_6b",
     "qwen1.5-32b": "qwen1_5_32b",

@@ -340,13 +340,28 @@ def build_sparq(cfg, mesh, dcfg: DistSparqConfig
                                 in_specs=row_spec, out_specs=row_spec,
                                 check_vma=False)
 
+    # the dropless expert layer's counters, per node, over microbatches:
+    # routings into held experts add up, the load ratio takes the worst
+    moe_stats = ({"moe_rows": jnp.add, "moe_load_max": jnp.maximum}
+                 if cfg.n_experts and cfg.dropless else {})
+
     def loss_fn(row, b):
-        return lm_loss(cfg, unravel(row), b)[0]
+        loss, m = lm_loss(cfg, unravel(row), b)
+        if not moe_stats:
+            return loss
+        return loss, {k: m[k] for k in moe_stats}
 
     def node_losses_grads(params, batch):
-        vg = jax.vmap(jax.value_and_grad(loss_fn))
+        """-> per node (losses, grads, counters)."""
+        vg = jax.vmap(jax.value_and_grad(loss_fn, has_aux=bool(moe_stats)))
+
+        def one(b):
+            out, g = vg(params, b)
+            return (*out, g) if moe_stats else (out, {}, g)
+
         if mbs == 1:
-            return vg(params, batch)
+            losses, stats, grads = one(batch)
+            return losses, grads, stats
 
         def split(x):
             nn, per = x.shape[:2]
@@ -354,14 +369,16 @@ def build_sparq(cfg, mesh, dcfg: DistSparqConfig
                 x.reshape((nn, mbs, per // mbs) + x.shape[2:]), 1, 0)
 
         def body(carry, bmb):
-            l_acc, g_acc = carry
-            li, gi = vg(params, bmb)
-            return (l_acc + li, g_acc + gi), None
+            l_acc, g_acc, s_acc = carry
+            li, si, gi = one(bmb)
+            s_new = {k: f(s_acc[k], si[k]) for k, f in moe_stats.items()}
+            return (l_acc + li, g_acc + gi, s_new), None
 
-        zeros = (jnp.zeros((n,), jnp.float32), jnp.zeros_like(params))
-        (l_tot, g_tot), _ = jax.lax.scan(body, zeros,
-                                         jax.tree.map(split, batch))
-        return l_tot / mbs, g_tot / mbs
+        zeros = (jnp.zeros((n,), jnp.float32), jnp.zeros_like(params),
+                 {k: jnp.zeros((n,), jnp.float32) for k in moe_stats})
+        (l_tot, g_tot, stats), _ = jax.lax.scan(body, zeros,
+                                                jax.tree.map(split, batch))
+        return l_tot / mbs, g_tot / mbs, stats
 
     def mix_term(xh, W_r):
         """Consensus term (W_r x_hat - x_hat) over the leading node axis."""
@@ -389,7 +406,7 @@ def build_sparq(cfg, mesh, dcfg: DistSparqConfig
         # No scope may name sign_topk or pallas (analysis/comm_lint.py's
         # interpret markers would hide a gossip collective under it).
         with jax.named_scope("sparq_grad"):
-            losses, grads = node_losses_grads(state["params"], batch)
+            losses, grads, stats = node_losses_grads(state["params"], batch)
             loss = jnp.mean(losses)
         eta = dcfg.lr(state["t"]).astype(jnp.float32)
         with jax.named_scope("sparq_optimizer"):
@@ -469,6 +486,11 @@ def build_sparq(cfg, mesh, dcfg: DistSparqConfig
                    "bits": bits.astype(jnp.float32),
                    "sync_rounds": rounds.astype(jnp.float32),
                    "triggers": trigs.astype(jnp.float32)}
+        if moe_stats:
+            # a node's routings into its held experts (the node mean), and
+            # the worst node's busiest held expert over the held mean
+            metrics["moe_rows"] = jnp.mean(stats["moe_rows"])
+            metrics["moe_load_max"] = jnp.max(stats["moe_load_max"])
         return new_state, metrics
 
     # static-audit metadata (repro.analysis R5/K2): whether the kernel path

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 
 from repro.kernels import resolve_lowering
 from repro.kernels.qsgd import BLOCK, qsgd_blocks
-from repro.kernels.sign_topk import BLOCK_ROWS, sign_topk_blocks
+from repro.kernels.sign_topk import BLOCK_ROWS, sign_topk_blocks, sign_topk_q
 
 
 def _to_blocks(x: jax.Array) -> Tuple[jax.Array, int, int]:
@@ -47,8 +47,7 @@ def sign_topk(flat: jax.Array, k: int, interpret: Optional[bool] = None,
     lw = resolve_lowering(lowering, interpret)
     xb, d, n = _to_blocks(flat)
     k_b = max(1, -(-k // n))
-    q, _, _ = sign_topk_blocks(xb, jnp.zeros_like(xb), jnp.float32(1.0),
-                               k_b, lowering=lw)
+    q, _ = sign_topk_q(xb, k_b, lowering=lw)
     # compact payload per tile: |support| <= k_b (exact-k selection), so the
     # tile-local top_k index set contains the whole support; gathering VALUES
     # from q keeps zeros in surplus slots -> scatter is lossless
@@ -87,7 +86,7 @@ def sign_topk_ensemble(diff: jax.Array, k_b: int,
     diff: (n_nodes, d) — one row per node's flat (already trigger-gated or
     ungated) parameter difference. Each row is padded to nb*BLOCK tiles (nb
     rounded up so the stacked (n_nodes*nb, BLOCK) grid divides BLOCK_ROWS)
-    and every tile is compressed in a single kernel call with trig=1; the
+    and every tile is compressed in a single q-only kernel call; the
     caller applies any per-node trigger gate outside (q is linear in the
     gate). Zero-padded tail tiles emit q == 0 by the exact-k zero-lane rule.
     Returns q: (n_nodes, d), same dtype as diff."""
@@ -99,8 +98,7 @@ def sign_topk_ensemble(diff: jax.Array, k_b: int,
         nb += 1  # grow the per-node tile count until the grid divides
         rows = min(BLOCK_ROWS, n * nb)
     xb = jnp.pad(diff, ((0, 0), (0, nb * BLOCK - d))).reshape(n * nb, BLOCK)
-    q, _, _ = sign_topk_blocks(xb, jnp.zeros_like(xb), jnp.float32(1.0),
-                               k_b, lowering=lw)
+    q, _ = sign_topk_q(xb, k_b, lowering=lw)
     return q.reshape(n, nb * BLOCK)[:, :d]
 
 

@@ -7,6 +7,9 @@ bit patterns + index-ordered tie break — pure VPU, no MXU, no sort), the
 SignTopK message q = trig * scale * sign(diff) on the support, and the
 updated estimate x_hat + q — all in one kernel, instead of the 4 separate
 HBM sweeps an unfused implementation costs (diff, top_k, scatter, add).
+``sign_topk_q`` is the same kernel body on one input, the difference
+itself, returning only q and the scales (the ensemble path gates and adds
+outside, so it needs neither an x_hat row nor x_hat + q).
 
 Selection contract (shared by the kernel, the XLA leg and kernels/ref.py):
 per tile, the support is EXACTLY the index set ``jax.lax.top_k(|diff|, k_b)``
@@ -145,6 +148,13 @@ def _sign_topk_kernel(xh_ref, xe_ref, trig_ref, q_ref, xe_new_ref, scale_ref,
     scale_ref[...] = scale
 
 
+def _sign_topk_q_kernel(x_ref, q_ref, scale_ref, *, k_b: int):
+    q32, scale = _block_compress(x_ref[...].astype(jnp.float32),
+                                 jnp.float32(1.0), k_b)
+    q_ref[...] = q32.astype(q_ref.dtype)
+    scale_ref[...] = scale
+
+
 def _sign_topk_xla(x_half: jax.Array, x_hat: jax.Array, trig: jax.Array,
                    k_b: int) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Compiled leg: the same per-row block math over the whole (n, BLOCK)
@@ -183,29 +193,50 @@ def sign_topk_blocks(x_half: jax.Array, x_hat: jax.Array, trig: jax.Array,
     trig_arr = jnp.asarray(trig, jnp.float32)
     if lw == "xla":
         return _sign_topk_xla(x_half, x_hat, trig_arr, k_b)
+    q, xe_new, scale = _tile_call(
+        functools.partial(_sign_topk_kernel, k_b=k_b), 2, x_half,
+        [x_half, x_hat], [trig_arr.reshape(1)], lw)
+    return q, xe_new, scale[:, 0]
+
+
+def _tile_call(kernel, n_rows_out: int, like: jax.Array, rows_in, scalars,
+               lw: str):
+    """The Pallas call of a SignTopK kernel body over (n, BLOCK) tiles in
+    slabs of ``slab_rows(n)``: row-sized inputs, then (1,) scalars; row
+    outputs like ``like``, then the (n, 1) float32 scales."""
+    n = like.shape[0]
     rows = slab_rows(n)
-    grid = (n // rows,)
-    q, xe_new, scale = pl.pallas_call(
-        functools.partial(_sign_topk_kernel, k_b=k_b),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((rows, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((rows, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=[
-            pl.BlockSpec((rows, BLOCK), lambda i: (i, 0)),
-            pl.BlockSpec((rows, BLOCK), lambda i: (i, 0)),
-            # 2-D (rows, 1) block: Mosaic refuses rank-1 blocks that are not
-            # a multiple of 128 lanes
-            pl.BlockSpec((rows, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, BLOCK), x_half.dtype),
-            jax.ShapeDtypeStruct((n, BLOCK), x_half.dtype),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        ],
+    tiles = pl.BlockSpec((rows, BLOCK), lambda i: (i, 0))
+    return pl.pallas_call(
+        kernel,
+        grid=(n // rows,),
+        in_specs=[tiles] * len(rows_in)
+        + [pl.BlockSpec((1,), lambda i: (0,))] * len(scalars),
+        # 2-D (rows, 1) scale block: Mosaic refuses rank-1 blocks that are
+        # not a multiple of 128 lanes
+        out_specs=[tiles] * n_rows_out
+        + [pl.BlockSpec((rows, 1), lambda i: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((n, BLOCK), like.dtype)] * n_rows_out
+        + [jax.ShapeDtypeStruct((n, 1), jnp.float32)],
         interpret=(lw == "interpret"),
         name=KERNEL_NAME,
-    )(x_half, x_hat, trig_arr.reshape(1))
-    return q, xe_new, scale[:, 0]
+    )(*rows_in, *scalars)
+
+
+@functools.partial(jax.jit, static_argnames=("k_b", "interpret", "lowering"))
+def sign_topk_q(x_half: jax.Array, k_b: int, interpret: Optional[bool] = None,
+                lowering: Optional[str] = None
+                ) -> Tuple[jax.Array, jax.Array]:
+    """x_half: (n_blocks, BLOCK), the difference to compress. Returns (q,
+    per-block scale): ``sign_topk_blocks(x_half, 0, 1.)``'s q and scale, bit
+    for bit, with no x_hat input and no x_hat + q output."""
+    lw = resolve_lowering(lowering, interpret)
+    n, b = x_half.shape
+    assert b == BLOCK, f"inner dim must be {BLOCK}"
+    if lw == "xla":
+        q32, scale = _block_compress(x_half.astype(jnp.float32),
+                                     jnp.float32(1.0), k_b)
+        return q32.astype(x_half.dtype), scale[:, 0]
+    q, scale = _tile_call(functools.partial(_sign_topk_q_kernel, k_b=k_b),
+                          1, x_half, [x_half], [], lw)
+    return q, scale[:, 0]

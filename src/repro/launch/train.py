@@ -330,9 +330,12 @@ def run(args: argparse.Namespace,
                 if (i + 1) % args.log_every == 0:
                     with ann("log"):
                         m = {k: float(v) for k, v in metrics.items()}
+                        moe = (f" moe_rows {m['moe_rows']:.0f} "
+                               f"moe_load_max {m['moe_load_max']:.3f}"
+                               if "moe_rows" in m else "")
                         print(f"[train] step {i+1:5d} loss {m['loss']:.4f} "
                               f"eta {m['eta']:.4f} bits {m['bits']:.3e} "
-                              f"triggers {m['triggers']:.0f}")
+                              f"triggers {m['triggers']:.0f}{moe}")
                 if args.ckpt_dir and args.ckpt_every and \
                         (i + 1) % args.ckpt_every == 0:
                     with ann("checkpoint"):

@@ -14,7 +14,9 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
-from repro.models.layers import apply_rope, dense_init, rms_norm_vec
+from repro.models.layers import (apply_rope, dense_init, rms_norm_vec,
+                                 yarn_frequencies, yarn_mscale,
+                                 yarn_score_mscale)
 
 Params = Dict[str, jax.Array]
 
@@ -65,11 +67,13 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                       q_pos: jax.Array, k_pos: jax.Array,
                       window: Optional[int] = None,
                       q_chunk: int = 1024, k_chunk: int = 2048,
-                      score_dtype=jnp.bfloat16) -> jax.Array:
+                      score_dtype=jnp.bfloat16,
+                      scale: Optional[float] = None) -> jax.Array:
     """Memory-linear causal attention (flash-style running softmax).
 
     q: (B, Sq, H, hd); k: (B, Sk, Hkv, hd); v: (B, Sk, Hkv, hdv).
     GQA: H must be a multiple of Hkv. Mask: k_pos <= q_pos (< window back).
+    ``scale`` multiplies the scores (default 1/sqrt(hd)).
     Returns (B, Sq, H, hdv) in q.dtype.
 
     Scores and softmax weights are carried in `score_dtype` (bf16) with f32
@@ -91,7 +95,8 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     qc = _divisor_chunk(sq, q_chunk)
     kc = _divisor_chunk(sk, k_chunk)
     nq, nk = sq // qc, sk // kc
-    scale = 1.0 / math.sqrt(hd)
+    if scale is None:
+        scale = 1.0 / math.sqrt(hd)
 
     qg = q.reshape(b, nq, qc, hkv, g, hd)
     kg = k.reshape(b, nk, kc, hkv, hd)
@@ -144,7 +149,8 @@ def chunked_attention(q: jax.Array, k: jax.Array, v: jax.Array,
 
 # ------------------------------------------------------------------ GQA forward
 
-def causal_parts_attention(cfg: ModelConfig, q, k, v, positions):
+def causal_parts_attention(cfg: ModelConfig, q, k, v, positions,
+                           scale: Optional[float] = None):
     """Causal attention in P query parts, part i attending only its kv prefix
     [0, (i+1)S/P) — cuts the quadratic term to ~(P+1)/2P of full S^2
     (EXPERIMENTS.md §Perf: beyond-paper prefill compute optimization).
@@ -153,7 +159,7 @@ def causal_parts_attention(cfg: ModelConfig, q, k, v, positions):
     b, s, h, hd = q.shape
     if P <= 1 or s % P or s // P < 128:
         return chunked_attention(q, k, v, positions, positions,
-                                 window=cfg.sliding_window)
+                                 window=cfg.sliding_window, scale=scale)
     part = s // P
     outs = []
     for i in range(P):
@@ -162,7 +168,7 @@ def causal_parts_attention(cfg: ModelConfig, q, k, v, positions):
         outs.append(chunked_attention(
             q_i, k[:, :kv_end], v[:, :kv_end],
             positions[i * part:(i + 1) * part], positions[:kv_end],
-            window=cfg.sliding_window))
+            window=cfg.sliding_window, scale=scale))
     return jnp.concatenate(outs, axis=1)
 
 
@@ -228,7 +234,7 @@ def decode_attention(cfg: ModelConfig, p: Params, x: jax.Array,
     return o, (cache_k, cache_v, cache_pos)
 
 
-# ------------------------------------------------------------------ MLA (DeepSeek-V3)
+# ------------------------------------------------------------------ MLA (DeepSeek-V2/V3)
 
 def init_mla(cfg: ModelConfig, key) -> Params:
     d, h = cfg.d_model, cfg.n_heads
@@ -248,7 +254,26 @@ def init_mla(cfg: ModelConfig, key) -> Params:
         p["w_uq"] = dense_init(ks[6], (cfg.q_lora_rank, h * (dn + dr)), dt)
     else:
         p["w_q"] = dense_init(ks[7], (d, h * (dn + dr)), dt)
+    p["kv_norm"] = jnp.ones((r_kv,), dt)
     return p
+
+
+def _mla_latent(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
+    """c_kv = x W_dkv, RMS-normed (kv_a_layernorm)."""
+    cd = jnp.dtype(cfg.compute_dtype)
+    c_kv = x @ p["w_dkv"].astype(cd)
+    cf = c_kv.astype(jnp.float32)
+    cf = cf * jax.lax.rsqrt(jnp.mean(cf * cf, -1, keepdims=True)
+                            + cfg.norm_eps)
+    return (cf * p["kv_norm"].astype(jnp.float32)).astype(cd)
+
+
+def _mla_rope(cfg: ModelConfig, x: jax.Array, positions: jax.Array):
+    """RoPE on the MLA rope dims, YaRN-scaled where the config says."""
+    m = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+         / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+    return apply_rope(x, positions, 1.0, cfg.rope_theta,
+                      inv=yarn_frequencies(cfg, cfg.qk_rope_dim), mscale=m)
 
 
 def _mla_q(cfg: ModelConfig, p: Params, x: jax.Array):
@@ -270,15 +295,18 @@ def mla_forward(cfg: ModelConfig, p: Params, x: jax.Array,
     b, s, _ = x.shape
     h, dn, dr, dv = cfg.n_heads, cfg.qk_nope_dim, cfg.qk_rope_dim, cfg.v_head_dim
     q_nope, q_rope = _mla_q(cfg, p, x)
-    q_rope = apply_rope(q_rope, positions[None, :], 1.0, cfg.rope_theta)
-    c_kv = x @ p["w_dkv"].astype(cd)                                  # (B,S,r)
+    q_rope = _mla_rope(cfg, q_rope, positions[None, :])
+    c_kv = _mla_latent(cfg, p, x)                                     # (B,S,r)
     k_rope = (x @ p["w_kr"].astype(cd)).reshape(b, s, 1, dr)
-    k_rope = apply_rope(k_rope, positions[None, :], 1.0, cfg.rope_theta)
+    k_rope = _mla_rope(cfg, k_rope, positions[None, :])
     k_nope = (c_kv @ p["w_uk"].astype(cd)).reshape(b, s, h, dn)
     v = (c_kv @ p["w_uv"].astype(cd)).reshape(b, s, h, dv)
     q = jnp.concatenate([q_nope, q_rope], -1)
     k = jnp.concatenate([k_nope, jnp.broadcast_to(k_rope, (b, s, h, dr))], -1)
-    out = causal_parts_attention(cfg, q, k, v, positions)
+    m2 = yarn_score_mscale(cfg)
+    out = causal_parts_attention(
+        cfg, q, k, v, positions,
+        scale=None if m2 == 1.0 else m2 / math.sqrt(dn + dr))
     return out.reshape(b, s, -1) @ p["wo"].astype(cd)
 
 
@@ -306,10 +334,10 @@ def mla_decode(cfg: ModelConfig, p: Params, x: jax.Array,
                         cfg.v_head_dim, cfg.kv_lora_rank)
     q_nope, q_rope = _mla_q(cfg, p, x)                        # (B,1,H,dn/dr)
     pos_arr = jnp.full((1,), pos, jnp.int32)
-    q_rope = apply_rope(q_rope, pos_arr[None, :], 1.0, cfg.rope_theta)
-    ckv_new = x @ p["w_dkv"].astype(cd)                       # (B,1,r)
+    q_rope = _mla_rope(cfg, q_rope, pos_arr[None, :])
+    ckv_new = _mla_latent(cfg, p, x)                          # (B,1,r)
     kr_new = (x @ p["w_kr"].astype(cd)).reshape(b, 1, 1, dr)
-    kr_new = apply_rope(kr_new, pos_arr[None, :], 1.0, cfg.rope_theta)
+    kr_new = _mla_rope(cfg, kr_new, pos_arr[None, :])
     slot = pos % c
     cache_ckv = jax.lax.dynamic_update_slice(cache_ckv, ckv_new, (0, slot, 0))
     cache_kr = jax.lax.dynamic_update_slice(cache_kr, kr_new[:, :, 0, :],
@@ -324,6 +352,9 @@ def mla_decode(cfg: ModelConfig, p: Params, x: jax.Array,
     s_rope = jnp.einsum("bhd,bcd->bhc", q_rope[:, 0].astype(jnp.float32),
                         cache_kr.astype(jnp.float32))
     s = (s_lat + s_rope) / math.sqrt(dn + dr)
+    m2 = yarn_score_mscale(cfg)
+    if m2 != 1.0:
+        s = s * m2
     valid = jnp.logical_and(cache_pos >= 0, cache_pos <= pos)
     if cfg.sliding_window is not None:
         valid = jnp.logical_and(valid, cache_pos > pos - cfg.sliding_window)

@@ -21,6 +21,7 @@ class ModelConfig:
     qkv_bias: bool = False           # qwen1.5
     qk_norm: bool = False            # chameleon
     norm: str = "rmsnorm"            # rmsnorm | layernorm
+    norm_eps: float = 1e-5
     act: str = "swiglu"              # swiglu | gelu | relu2 (minitron/nemotron)
     rope_pct: float = 1.0            # stablelm-2 uses 0.25
     rope_theta: float = 10000.0
@@ -32,8 +33,11 @@ class ModelConfig:
     moe_top_k: int = 0
     moe_d_ff: int = 0                # per-expert hidden dim (fine-grained)
     first_k_dense: int = 0           # leading dense-FFN layers (deepseek)
-    capacity_factor: float = 1.25
+    capacity_factor: float = 1.25    # 0 -> dropless: every routed token runs
     router_aux_coef: float = 0.001
+    norm_topk_prob: bool = True      # renormalize the top-k gates to sum 1
+    experts_held: int = 0            # dropless only: experts [0, held) live
+                                     # here (this chip's share); 0 -> all
 
     # SSM (mamba2 SSD)
     ssm_state: int = 0
@@ -46,13 +50,19 @@ class ModelConfig:
     # hybrid (zamba2): shared attention block applied every `attn_every` layers
     attn_every: int = 0
 
-    # MLA (deepseek-v3)
+    # MLA (deepseek-v2 / v3; the latent c_kv is RMS-normed, kv_a_layernorm)
     use_mla: bool = False
     q_lora_rank: int = 0             # 0 -> full-rank q projection
     kv_lora_rank: int = 512
     qk_rope_dim: int = 64
     qk_nope_dim: int = 128
     v_head_dim: int = 128
+
+    # YaRN RoPE scaling of the MLA rope dims (DeepSeek-V2/V3; its ramp's
+    # constants are layers.YARN_*); rope_factor 1 -> plain RoPE
+    rope_factor: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
     # MTP (deepseek-v3 multi-token prediction)
     use_mtp: bool = False
@@ -93,6 +103,14 @@ class ModelConfig:
         return self.head_dim if self.head_dim else self.d_model // self.n_heads
 
     @property
+    def dropless(self) -> bool:
+        return self.capacity_factor == 0
+
+    @property
+    def resolved_experts_held(self) -> int:
+        return self.experts_held or self.n_experts
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -119,6 +137,7 @@ class ModelConfig:
             d_ff=max(64, min(self.d_ff, d_model * 3)),
             vocab_size=min(self.vocab_size, vocab),
             n_experts=ne,
+            experts_held=min(self.experts_held, ne),
             n_shared_experts=min(self.n_shared_experts, 1),
             moe_top_k=min(self.moe_top_k, 2) if self.moe_top_k else 0,
             moe_d_ff=min(self.moe_d_ff, 128) if self.moe_d_ff else 0,

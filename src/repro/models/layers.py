@@ -1,7 +1,8 @@
 """Shared layers: norms, RoPE, MLPs, embeddings. Pure functions over dict pytrees."""
 from __future__ import annotations
 
-from typing import Dict
+import math
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +30,8 @@ def init_norm(cfg: ModelConfig, key) -> Params:
     return p
 
 
-def apply_norm(cfg: ModelConfig, p: Params, x: jax.Array, eps: float = 1e-5):
+def apply_norm(cfg: ModelConfig, p: Params, x: jax.Array):
+    eps = cfg.norm_eps
     xf = x.astype(jnp.float32)
     if cfg.norm == "layernorm":
         mu = jnp.mean(xf, -1, keepdims=True)
@@ -58,15 +60,68 @@ def rope_frequencies(head_dim: int, rope_pct: float, theta: float):
     return inv, rot
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, rope_pct: float, theta: float):
-    """x: (..., S, H, hd); positions: (..., S) int32."""
+# YaRN's correction range and original context, as DeepSeek-V2 and V3
+# publish them (rope_scaling beta_fast, beta_slow,
+# original_max_position_embeddings)
+YARN_BETA_FAST = 32
+YARN_BETA_SLOW = 1
+YARN_ORIGINAL_MAX_POS = 4096
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_frequencies(cfg: ModelConfig, dim: int):
+    """YaRN's inverse frequencies over ``dim`` rotated dims (DeepSeek-V2's
+    ``DeepseekV2YarnRotaryEmbedding``): the original frequencies above the
+    correction range, those divided by the factor below it, a linear ramp
+    between. Plain RoPE's where ``rope_factor`` is 1."""
+    theta, factor = cfg.rope_theta, cfg.rope_factor
+    extra = 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    if factor == 1.0:
+        return extra
+
+    def corr_dim(rotations):
+        return (dim * math.log(YARN_ORIGINAL_MAX_POS
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(corr_dim(YARN_BETA_FAST)), 0)
+    high = min(math.ceil(corr_dim(YARN_BETA_SLOW)), dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
+def yarn_score_mscale(cfg: ModelConfig) -> float:
+    """YaRN's factor on the softmax scale head_dim**-0.5: mscale(all_dim)
+    squared (1 without YaRN)."""
+    m = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+         if cfg.rope_mscale_all_dim else 1.0)
+    return m * m
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, rope_pct: float, theta: float,
+               inv: Optional[jax.Array] = None, mscale: float = 1.0):
+    """x: (..., S, H, hd); positions: (..., S) int32. ``inv``: inverse
+    frequencies of the rotated dims (default: plain RoPE at ``theta``);
+    ``mscale`` scales cos and sin."""
     hd = x.shape[-1]
-    inv, rot = rope_frequencies(hd, rope_pct, theta)
+    if inv is None:
+        inv, rot = rope_frequencies(hd, rope_pct, theta)
+    else:
+        rot = 2 * inv.shape[0]
     if rot == 0:
         return x
     ang = positions[..., :, None].astype(jnp.float32) * inv  # (..., S, rot/2)
     cos = jnp.cos(ang)[..., :, None, :]
     sin = jnp.sin(ang)[..., :, None, :]
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
     xr, xp = x[..., :rot], x[..., rot:]
     x1, x2 = xr[..., 0::2], xr[..., 1::2]
     y1 = x1 * cos - x2 * sin

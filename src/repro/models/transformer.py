@@ -112,6 +112,9 @@ def _apply_moe_block(cfg: ModelConfig, bp: Params, x, positions):
     else:
         x = x + attn.attention_forward(cfg, bp["attn"], h, positions)
     h2 = apply_norm(cfg, bp["norm2"], x)
+    if cfg.dropless:
+        y, aux, stats = moe_mod.dropless_forward(cfg, bp["moe"], h2)
+        return x + y, (aux, stats)
     y, aux = moe_mod.moe_forward(cfg, bp["moe"], h2)
     return x + y, aux
 
@@ -135,6 +138,17 @@ def forward_hidden(cfg: ModelConfig, params: Params,
     """Backbone forward. tokens: (B, S) int32, or `embeds` (B, S, D) precomputed
     frontend embeddings (audio/VLM stub carve-out) -> (hidden (B,S,D) final-
     norm'd, aux)."""
+    x, aux, _ = _backbone(cfg, params, tokens, embeds)
+    return x, aux
+
+
+def _backbone(cfg: ModelConfig, params: Params, tokens: Optional[jax.Array],
+              embeds: Optional[jax.Array]
+              ) -> Tuple[jax.Array, jax.Array, Dict[str, jax.Array]]:
+    """forward_hidden, plus the dropless expert layers' counters summed
+    over them: ``moe_rows`` (pairs routed to held experts) and
+    ``moe_load_max`` (the worst layer's busiest held expert over the held
+    mean); none for other models."""
     if embeds is not None:
         x = embeds.astype(jnp.dtype(cfg.compute_dtype))
         b, s, _ = embeds.shape
@@ -147,6 +161,7 @@ def forward_hidden(cfg: ModelConfig, params: Params,
             x, _P(cfg.batch_axes, *([None] * (x.ndim - 1))))
     positions = jnp.arange(s, dtype=jnp.int32)
     aux_total = jnp.zeros((), jnp.float32)
+    stats: Dict[str, jax.Array] = {}
 
     for si, (kind, _n) in enumerate(segments(cfg)):
         seg_params = params[f"seg{si}"]
@@ -182,12 +197,16 @@ def forward_hidden(cfg: ModelConfig, params: Params,
         else:
             if kind == "moe":
                 x, auxs = jax.lax.scan(wrapped, x, seg_params)
+                if cfg.dropless:
+                    auxs, st = auxs
+                    stats = {"moe_rows": jnp.sum(st["rows"]),
+                             "moe_load_max": jnp.max(st["load_max"])}
                 aux_total = aux_total + jnp.sum(auxs)
             else:
                 x, _ = jax.lax.scan(wrapped, x, seg_params)
 
     x = apply_norm(cfg, params["final_norm"], x)
-    return x, aux_total
+    return x, aux_total, stats
 
 
 def forward(cfg: ModelConfig, params: Params, tokens: Optional[jax.Array] = None,
@@ -255,9 +274,9 @@ def lm_loss(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array]
     labels = batch["labels"]
     tokens = batch.get("tokens")
     embeds = batch.get("embeds")
-    hidden, aux = forward_hidden(cfg, params, tokens, embeds=embeds)
+    hidden, aux, stats = _backbone(cfg, params, tokens, embeds)
     loss = chunked_ce(cfg, params["embed"], hidden, labels)
-    metrics = {"ce": loss, "aux": aux}
+    metrics = {"ce": loss, "aux": aux, **stats}
     if cfg.n_experts:
         loss = loss + cfg.router_aux_coef * aux
     if cfg.use_mtp:

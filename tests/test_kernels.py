@@ -13,6 +13,7 @@ from repro.kernels.sign_topk import (
     BLOCK,
     BLOCK_ROWS,
     sign_topk_blocks,
+    sign_topk_q,
     slab_rows,
 )
 
@@ -210,6 +211,24 @@ def test_sign_topk_legs_bit_equal_to_oracle(dtype, n_tiles):
     assert (nnz[2::5] == BLOCK // 16).all()
 
 
+@pytest.mark.parametrize("n_tiles", [8, 13, 2 * BLOCK_ROWS,
+                                     2 * BLOCK_ROWS + 8])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_sign_topk_q_bit_equal_to_blocks(dtype, n_tiles):
+    """The q-only entry (no x_hat input, no x_hat + q output) gives
+    sign_topk_blocks's q and scales bit for bit on every leg, from the
+    difference alone: x_half - 0 with trig 1, on the same tied, zero and
+    sparse tiles."""
+    x, _ = _mixed_tiles(jax.random.PRNGKey(11), n_tiles, dtype)
+    for leg in LEGS:
+        q_b, _, sc_b = sign_topk_blocks(x, jnp.zeros_like(x),
+                                        jnp.float32(1.0), 102, lowering=leg)
+        q, sc = sign_topk_q(x, 102, lowering=leg)
+        assert q.dtype == x.dtype and sc.dtype == jnp.float32
+        np.testing.assert_array_equal(np.asarray(q), np.asarray(q_b))
+        np.testing.assert_array_equal(np.asarray(sc), np.asarray(sc_b))
+
+
 @pytest.mark.parametrize("n_tiles", [1, 13, BLOCK_ROWS, 2 * BLOCK_ROWS,
                                      2 * BLOCK_ROWS + 8, 4727 * 128])
 def test_slab_rows_divides_the_tile_count(n_tiles):
@@ -350,3 +369,52 @@ def test_legs_bit_equal_bf16_ragged():
     outs = [ops.sign_topk(x, 200, lowering=leg) for leg in LEGS]
     for a, b in zip(outs[0], outs[1], strict=True):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("sizes", [[5, 0, 130, 7], [0, 0, 0, 300]])
+def test_gmm_legs_agree_and_follow_the_tiles(sizes):
+    """The grouped product's Pallas kernel (in the interpreter) and its
+    XLA leg give the same products and gradients in the rows and the
+    matrices, up to bfloat16's rounding of float32 sums taken in another
+    order; each active tile is its group's product, a group with no rows
+    still owns a tile, and the tiles past n_active are zero."""
+    from repro.kernels import gmm as G
+    sizes = jnp.asarray(sizes, jnp.int32)
+    rows = 8 * G.TILE_M
+    first, tile_group, n_active = G.layout(sizes, rows)
+    live = np.zeros(rows, bool)
+    for o, s in zip(np.asarray(first), np.asarray(sizes)):
+        live[o:o + s] = True
+    lhs = jnp.where(jnp.asarray(live)[:, None], jax.random.normal(
+        jax.random.PRNGKey(0), (rows, 64)), 0.0).astype(jnp.bfloat16)
+    rhs = jax.random.normal(jax.random.PRNGKey(1), (4, 64, 96)
+                            ).astype(jnp.bfloat16)
+    w = jax.random.normal(jax.random.PRNGKey(2), (rows, 96))
+
+    def run(leg):
+        def f(a, b):
+            out = G.gmm(a, b, tile_group, n_active, lowering=leg)
+            return jnp.sum(out.astype(jnp.float32) * w), out
+        (_, out), grads = jax.value_and_grad(f, argnums=(0, 1),
+                                             has_aux=True)(lhs, rhs)
+        return out, grads
+
+    def close(a, b):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        np.testing.assert_allclose(a, b, atol=2 ** -8 * np.abs(b).max())
+
+    out_x, g_x = run("xla")
+    out_i, g_i = run("interpret")
+    close(out_x, out_i)
+    for a, b in zip(g_x, g_i):
+        close(a, b)
+    na = int(n_active[0])
+    assert na == int(np.maximum(1, -(-np.asarray(sizes) // G.TILE_M)).sum())
+    want = np.zeros((rows, 96), np.float32)
+    L, R = np.asarray(lhs, np.float32), np.asarray(rhs, np.float32)
+    for i in range(na):
+        sl = slice(i * G.TILE_M, (i + 1) * G.TILE_M)
+        want[sl] = L[sl] @ R[int(tile_group[i])]
+    np.testing.assert_allclose(np.asarray(out_i, np.float32), want,
+                               atol=0.02 * np.abs(want).max())
+    assert not np.asarray(out_i, np.float32)[na * G.TILE_M:].any()

@@ -8,7 +8,8 @@ no lowering for, a slab that overflows the 16 MiB scoped VMEM — at the real
 flat sizes: qwen1.5-0.5b with its untied head (D = 619,570,176 = 605,049
 tiles of 1024, which the train step's kernel call pads to 605,184 tiles, a
 whole number of BLOCK_ROWS-tile slabs), and the benchmark's rows,
-qwen1.5-0.5b tied and mamba2-370m.
+qwen1.5-0.5b tied, mamba2-370m and deepseek-v2-lite's one-chip share; and
+the dropless expert layer's grouped products at DeepSeek-V2-Lite's widths.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library, and every test worker imports
@@ -34,7 +35,8 @@ from repro.kernels.sign_topk import (
 QWEN_D = 619_570_176            # qwen1.5-0.5b raveled, = 605,049 * BLOCK
 # sign_topk_ensemble's padded tile count: whole BLOCK_ROWS-tile slabs
 QWEN_TILES = -(-QWEN_D // (BLOCK * BLOCK_ROWS)) * BLOCK_ROWS
-BENCH_D = {"qwen1.5-0.5b-tied": 463_987_712, "mamba2-370m": 368_338_432}
+BENCH_D = {"qwen1.5-0.5b-tied": 463_987_712, "mamba2-370m": 368_338_432,
+           "deepseek-v2-lite-share": 535_060_992}
 K_B = 103                       # ceil(0.1 * BLOCK)
 
 
@@ -99,3 +101,29 @@ def test_sign_topk_custom_call_carries_the_kernel_name(one_chip):
                        r"custom_call_target=\"tpu_custom_call\"", text)
     assert calls and all(re.fullmatch(re.escape(KERNEL_NAME) + r"(\.\d+)?",
                                       c) for c in calls), calls
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)],
+                         ids=["gate-up", "down"])
+def test_gmm_and_its_gradients_compile_for_v5e(one_chip, k, n):
+    """The grouped products of deepseek-v2-lite's expert layer at its
+    widths: 8 held experts, the buffer of 2 x 4096 tokens x 6 choices in
+    whole tiles plus one a group, forward and both backward kernels."""
+    from repro.kernels import gmm as G
+    rows = (-(-8192 * 6 // G.TILE_M) + 8) * G.TILE_M
+
+    def step(lhs, rhs, tile_group, n_active):
+        def f(a, b):
+            out = G.gmm(a, b, tile_group, n_active, lowering="pallas")
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+        return jax.grad(f, argnums=(0, 1))(lhs, rhs)
+
+    def sds(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    hlo = jax.jit(step).lower(
+        sds((rows, k), jnp.bfloat16), sds((8, k, n), jnp.bfloat16),
+        sds((rows // G.TILE_M,), jnp.int32), sds((1,), jnp.int32)
+    ).compile().as_text()
+    assert hlo.count("tpu_custom_call") == 3
+    assert G.GMM_NAME in hlo and G.TGMM_NAME in hlo
