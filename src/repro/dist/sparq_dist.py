@@ -10,6 +10,8 @@ exact-k compression and carry no gradient). Gossip, the trigger norm,
 ``x_hat``, the optimizer buffers and the bit accounting ALL operate on that
 flat view; the loss alone sees the model structure, through a precomputed
 static-slice ``unravel`` applied per node row inside ``value_and_grad``.
+Inside the step each row is seen as ``(D_pad // 128, 128)``, the same bytes
+in full (8, 128) vector tiles, and the gradient is built in that view.
 The trigger / consensus-mixing / bit-accounting primitives are imported from
 core (``trigger_mask``, ``gossip_mix``, ``sync_message_bits``) so the two
 engines cannot drift — tests/test_dist_equivalence.py pins them equal.
@@ -76,6 +78,7 @@ from repro.models.transformer import init_params, lm_loss
 from repro.optim.sgd import Optimizer, resolve_optimizer
 
 State = Dict[str, Any]
+LANES = 128   # a vector register's lanes; it has 8 sublanes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -280,13 +283,32 @@ def build_sparq(cfg, mesh, dcfg: DistSparqConfig
     treedef, slices, D = _flatten_spec(pshape)
     d_model_total = D
     D_pad = max(1, -(-D // BLOCK)) * BLOCK
+    # The step sees each node's row as (D_pad // 128, 128). XLA tiles a
+    # (1, D_pad) row T(1,128), which fills one of the 8 sublanes of each
+    # vector register; an (8, 128) tile of the view holds the same 1024
+    # consecutive elements, so the reshape either way is a bitcast. unravel
+    # cuts each leaf out of the 128-lane rows that cover it, so a leaf that
+    # starts and ends on a row (every leaf of the benchmark's models) is a
+    # slice of the view, and its gradient is written into the view too.
+    view_rows = D_pad // LANES
 
-    def unravel(flat: jax.Array):
-        """One node row (D_pad,) or (D,) -> model pytree (static slices)."""
+    def view(x):
+        return x.reshape(x.shape[0], view_rows, LANES)
+
+    def flat(x):
+        return x.reshape(x.shape[0], D_pad)
+
+    def unravel(row: jax.Array):
+        """One node row of D_pad elements (flat or in view) -> model pytree
+        (static slices of the rows that cover each leaf)."""
         with jax.named_scope("sparq_unravel"):
-            return jax.tree.unflatten(treedef, [
-                flat[off:off + size].reshape(leaf.shape).astype(leaf.dtype)
-                for off, size, leaf in slices])
+            rows = row.reshape(view_rows, LANES)
+            leaves = []
+            for off, size, leaf in slices:
+                lo, hi = off // LANES, -(-(off + size) // LANES)
+                part = rows[lo:hi].reshape(-1)[off - lo * LANES:][:size]
+                leaves.append(part.reshape(leaf.shape).astype(leaf.dtype))
+            return jax.tree.unflatten(treedef, leaves)
 
     def ravel(tree) -> jax.Array:
         """Model pytree -> (D,) f32 flat vector (leaf order of pshape)."""
@@ -330,6 +352,11 @@ def build_sparq(cfg, mesh, dcfg: DistSparqConfig
             "t": jnp.int32(0), "bits": bits0, "bits_c": bits_c0,
             "sync_rounds": jnp.int32(0), "triggers": jnp.int32(0),
         }
+
+    def rows_map(f, tree):
+        """``f`` on each node-stacked row of an optimizer state (its other
+        leaves, such as AdamW's step count, pass through)."""
+        return jax.tree.map(lambda l: f(l) if l.ndim > 1 else l, tree)
 
     def local_rows_compress(rows):
         return kernel_ops.sign_topk_ensemble(rows, k_b, lowering=lowering)
@@ -405,25 +432,32 @@ def build_sparq(cfg, mesh, dcfg: DistSparqConfig
         # and in the sync branch sparq_trigger, sparq_compress, sparq_mix.
         # No scope may name sign_topk or pallas (analysis/comm_lint.py's
         # interpret markers would hide a gossip collective under it).
+        # The rows are (n, D_pad) in the state and (n, D_pad // 128, 128)
+        # in the step. The barriers where the step takes the view and where
+        # it gives the rows back keep XLA from moving those reshapes across
+        # the element-wise passes (which would run them on the T(1,128)
+        # row) or into the cond's branches (which costs a copy of each row).
+        params, x_hat, opt_state = jax.lax.optimization_barrier(
+            (view(state["params"]), view(state["x_hat"]),
+             rows_map(view, state["opt"])))
         with jax.named_scope("sparq_grad"):
-            losses, grads, stats = node_losses_grads(state["params"], batch)
+            losses, grads, stats = node_losses_grads(params, batch)
             loss = jnp.mean(losses)
         eta = dcfg.lr(state["t"]).astype(jnp.float32)
         with jax.named_scope("sparq_optimizer"):
             # local update through the shared optimizer seam (optim/sgd.py):
             # plain SGD by default, heavyball/Nesterov for SQuARM-SGD
-            x_half, opt_new = opt.update(grads, state["opt"], state["params"],
-                                         eta)
+            x_half, opt_new = opt.update(grads, opt_state, params, eta)
             if flt is not None:
                 # stragglers / offline nodes skip this local step: iterate
                 # AND optimizer buffers freeze (same step_mask stream as the
                 # reference engine — core/faults.py determinism contract)
                 act = flt.step_mask(state["t"], n)               # (n,) bool
-                x_half = flt.gate_update(act, x_half, state["params"])
-                opt_new = flt.gate_update(act, opt_new, state["opt"])
+                x_half = flt.gate_update(act, x_half, params)
+                opt_new = flt.gate_update(act, opt_new, opt_state)
 
         def sync_branch(op):
-            xh, xe = op                       # (n, D_pad) f32 / xhat_dt
+            xh, xe = op               # (n, D_pad // 128, 128) f32 / xhat_dt
             # active round's graph: static plans bind W_0 so the lowered
             # program is identical to the fixed-topology days
             if R == 1:
@@ -434,7 +468,8 @@ def build_sparq(cfg, mesh, dcfg: DistSparqConfig
             with jax.named_scope("sparq_trigger"):
                 c_t = dcfg.threshold(state["t"])
                 diff = xh.astype(jnp.float32) - xe.astype(jnp.float32)
-                trig = trigger_mask(jnp.sum(diff * diff, axis=1), c_t, eta)
+                trig = trigger_mask(jnp.sum(diff * diff, axis=(1, 2)), c_t,
+                                    eta)
                 if flt is not None:
                     # faulty round: repaired W over the surviving links,
                     # offline nodes muted, bits charged for live links only
@@ -449,7 +484,7 @@ def build_sparq(cfg, mesh, dcfg: DistSparqConfig
                     # rows (kernels/ops.py; == vmapping BlockTopFrac
                     # row-by-row). Trigger gating happens below: q is linear
                     # in the 0/1 gate.
-                    q = kernel_rows(diff)
+                    q = view(kernel_rows(flat(diff)))
                 else:
                     # generic registry operator over the TRUE flat vector
                     # (n, D) rows — one global operator application per
@@ -458,9 +493,9 @@ def build_sparq(cfg, mesh, dcfg: DistSparqConfig
                     # (deterministic operators ignore them)
                     kc = jax.random.fold_in(base_key, state["t"])
                     q_d = jax.vmap(lambda v, k: comp(v, k))(
-                        diff[:, :D], jax.random.split(kc, n))
-                    q = jnp.pad(q_d, ((0, 0), (0, D_pad - D)))
-                q = q * trigf[:, None]                           # line 11
+                        flat(diff)[:, :D], jax.random.split(kc, n))
+                    q = view(jnp.pad(q_d, ((0, 0), (0, D_pad - D))))
+                q = q * trigf[:, None, None]                     # line 11
             with jax.named_scope("sparq_mix"):
                 xe_new = (xe.astype(jnp.float32) + q).astype(xhat_dt)  # 13
                 x_new = xh + gamma * mix_term(xe_new, W_r)       # line 15
@@ -478,8 +513,11 @@ def build_sparq(cfg, mesh, dcfg: DistSparqConfig
 
         do_sync = ((state["t"] + 1) % H) == 0
         x_new, xe_new, bits, bits_c, rounds, trigs = jax.lax.cond(
-            do_sync, sync_branch, local_branch, (x_half, state["x_hat"]))
-        new_state = {"params": x_new, "x_hat": xe_new, "opt": opt_new,
+            do_sync, sync_branch, local_branch, (x_half, x_hat))
+        x_new, xe_new, opt_new = jax.lax.optimization_barrier(
+            (x_new, xe_new, opt_new))
+        new_state = {"params": flat(x_new), "x_hat": flat(xe_new),
+                     "opt": rows_map(flat, opt_new),
                      "t": state["t"] + 1, "bits": bits, "bits_c": bits_c,
                      "sync_rounds": rounds, "triggers": trigs}
         metrics = {"loss": loss, "eta": eta,

@@ -10,6 +10,7 @@ must produce the same parameters, trigger counts and bit totals within
 float tolerance. The deliberate global-vs-per-tensor top-k semantic change
 of the flat-buffer path is pinned separately below."""
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -302,3 +303,125 @@ def test_dist_padded_tail_stays_zero():
             state, _ = step(state, batch)
         assert not np.any(np.asarray(state["params"][:, D:]))
         assert not np.any(np.asarray(state["x_hat"][:, D:]))
+
+
+def _flat_unravel(pshape, row):
+    """A flat row -> the model pytree, by static slices of the row."""
+    leaves, treedef = jax.tree.flatten(pshape)
+    offs = np.cumsum([0] + [int(np.prod(l.shape)) for l in leaves])
+    return jax.tree.unflatten(treedef, [
+        row[o:o + l.size].reshape(l.shape).astype(l.dtype)
+        for o, l in zip(offs, leaves)])
+
+
+def _flat_step(cfg, dcfg, train_step, pshape, state, batch):
+    """One SPARQ step written out plainly on the stored (n, D_pad) rows:
+    the reference the engine's row view must match bit for bit."""
+    from repro.core import bits as bits_mod
+    from repro.core.faults import COMPRESS_STREAM, resolve_faults
+    from repro.core.sparq import sync_message_bits, trigger_mask
+    from repro.kernels.ops import sign_topk_ensemble
+    n, D, D_pad = train_step.n_nodes, train_step.d_model_total, \
+        train_step.d_pad
+    opt, flt, t = dcfg.resolved_optimizer(), resolve_faults(dcfg.faults), \
+        state["t"]
+    _, grads = jax.vmap(jax.value_and_grad(
+        lambda row, b: lm_loss(cfg, _flat_unravel(pshape, row), b)[0]))(
+            state["params"], batch)
+    eta = dcfg.lr(t).astype(jnp.float32)
+    x_half, opt_new = opt.update(grads, state["opt"], state["params"], eta)
+    if flt is not None:
+        act = flt.step_mask(t, n)
+        x_half = flt.gate_update(act, x_half, state["params"])
+        opt_new = flt.gate_update(act, opt_new, state["opt"])
+    plan = train_step.plan
+
+    def sync(x_half):
+        r = state["sync_rounds"] % plan.R
+        W = jnp.asarray(plan.ws, jnp.float32)[r]
+        deg = jnp.asarray(plan.degrees, jnp.float32)[r]
+        diff = x_half - state["x_hat"]
+        trig = trigger_mask(jnp.sum(diff * diff, axis=1), dcfg.threshold(t),
+                            eta)
+        if flt is not None:
+            W, deg, live = flt.apply(W, t, state["sync_rounds"])
+            trig = trig & live
+        if dcfg.use_kernel:
+            q = sign_topk_ensemble(diff, dcfg.effective_compressor()._k_b(),
+                                   lowering=train_step.lowering)
+        else:
+            kc = jax.random.fold_in(jax.random.fold_in(
+                jax.random.PRNGKey(dcfg.seed), COMPRESS_STREAM), t)
+            q = jnp.pad(jax.vmap(dcfg.resolved_compressor())(
+                diff[:, :D], jax.random.split(kc, n)),
+                ((0, 0), (0, D_pad - D)))
+        x_hat = state["x_hat"] + q * trig.astype(jnp.float32)[:, None]
+        bits = bits_mod.acc_add(
+            state["bits"], state["bits_c"],
+            sync_message_bits(trig, deg, train_step.payload_bits))
+        return (x_half + train_step.gamma * gossip_mix(W, x_hat), x_hat,
+                *bits, state["sync_rounds"] + 1,
+                state["triggers"] + jnp.sum(trig).astype(jnp.int32))
+
+    def local(x_half):
+        return (x_half, state["x_hat"], state["bits"], state["bits_c"],
+                state["sync_rounds"], state["triggers"])
+
+    keys = ("params", "x_hat", "bits", "bits_c", "sync_rounds", "triggers")
+    out = jax.lax.cond((t + 1) % dcfg.H == 0, sync, local, x_half)
+    return dict(state, **dict(zip(keys, out)), opt=opt_new, t=t + 1)
+
+
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["generic", "kernel"])
+@pytest.mark.parametrize("faults", [False, True], ids=["clean", "faults"])
+@pytest.mark.parametrize("beta", [0.0, 0.9], ids=["sgd", "momentum"])
+@pytest.mark.parametrize("n", [1, 4])
+def test_row_view_step_bit_equal_to_the_flat_step(monkeypatch, n, beta,
+                                                  faults, use_kernel):
+    """The step runs its row passes, and builds each node's gradient, on a
+    (D_pad // 128, 128) view of the (n, D_pad) rows. Over 2 H steps (two
+    syncs) params, x_hat and the optimizer rows stay bit-equal to the same
+    step written out on the flat rows, the counters equal, the stored
+    shapes (n, D_pad) and the padded tail zero; the kernel runs in the
+    Pallas interpreter."""
+    monkeypatch.setenv("REPRO_KERNEL_LOWERING", "interpret")
+    cfg = dataclasses.replace(
+        get_config("qwen1.5-0.5b").reduced(n_layers=1, d_model=128,
+                                           vocab=256), n_nodes=n)
+    mesh = sh.train_mesh(make_mesh((1, 1), ("data", "model")), cfg)
+    fp = (FaultPlan(link_drop=0.3, stragglers=(n - 1,), straggler_frac=0.5,
+                    dropout=(DropoutWindow(n // 2, 1, 3),), seed=5)
+          if faults else None)
+    H = 2
+    dcfg = DistSparqConfig(H=H, variant="dense", frac=0.25, threshold=zero(),
+                           lr=fixed(0.05), gamma=0.3, momentum=beta,
+                           use_kernel=use_kernel, faults=fp)
+    init_fn, train_step, _, pshape = build_sparq(cfg, mesh, dcfg)
+    assert train_step.lowering == "interpret"
+    rng = np.random.default_rng(n)
+    batch = {k: jnp.asarray(
+        rng.integers(0, cfg.vocab_size, (n, 2, 16)).astype(np.int32))
+        for k in ("tokens", "labels")}
+    state = ref = init_fn(jax.random.PRNGKey(0))
+    step = jax.jit(train_step)
+    ref_step = jax.jit(functools.partial(_flat_step, cfg, dcfg, train_step,
+                                         pshape))
+    for _ in range(2 * H):
+        state, _ = step(state, batch)
+        ref = ref_step(ref, batch)
+    D, D_pad = train_step.d_model_total, train_step.d_pad
+    rows = [("params", state["params"], ref["params"]),
+            ("x_hat", state["x_hat"], ref["x_hat"])]
+    rows += [("opt", a, b) for a, b in zip(jax.tree.leaves(state["opt"]),
+                                           jax.tree.leaves(ref["opt"]),
+                                           strict=True)]
+    assert len(rows) == (3 if beta else 2)
+    for name, got, want in rows:
+        assert got.shape == (n, D_pad), name
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want),
+                                      err_msg=name)
+        assert not np.any(np.asarray(got[:, D:])), name
+    for k in ("t", "sync_rounds", "triggers", "bits", "bits_c"):
+        assert np.asarray(state[k]) == np.asarray(ref[k]), k
+    assert int(state["sync_rounds"]) == 2

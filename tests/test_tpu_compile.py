@@ -16,7 +16,10 @@ only one process may load the TPU library, and every test worker imports
 this file. The persistent compile cache is off around these compiles (an
 entry compiled for a described chip cannot be read back without one).
 """
+import dataclasses
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -92,7 +95,6 @@ def test_sign_topk_custom_call_carries_the_kernel_name(one_chip):
     """The Pallas call's fixed name is the HLO custom call's (XLA may add a
     ``.N`` suffix), whatever jit wraps it; it starts with ``sign_topk``,
     which the benchmark's kernel reader matches."""
-    import re
     assert KERNEL_NAME.startswith("sign_topk")
     text = sign_topk_ensemble.lower(
         _sds((2, 64 * BLOCK), one_chip), K_B,
@@ -127,3 +129,110 @@ def test_gmm_and_its_gradients_compile_for_v5e(one_chip, k, n):
     ).compile().as_text()
     assert hlo.count("tpu_custom_call") == 3
     assert G.GMM_NAME in hlo and G.TGMM_NAME in hlo
+
+
+_INSTR = re.compile(r"\s*(?:ROOT )?%([\w.\-]+) = ")
+
+
+def _split_shape(rest):
+    """'shape opcode(operands), attrs' -> (shape, opcode, operands, attrs);
+    a tuple shape is parenthesized."""
+    if rest.startswith("("):
+        depth = 0
+        for i, c in enumerate(rest):
+            depth += {"(": 1, ")": -1}.get(c, 0)
+            if depth == 0:
+                break
+        shape, rest = rest[:i + 1], rest[i + 2:]
+    else:
+        shape, _, rest = rest.partition(" ")
+    opcode, _, rest = rest.partition("(")
+    depth, i = 1, 0
+    while depth and i < len(rest):
+        depth += {"(": 1, ")": -1}.get(rest[i], 0)
+        i += 1
+    return shape, opcode, re.findall(r"%([\w.\-]+)", rest[:i]), rest[i:]
+
+
+def _elements(shape):
+    return [math.prod(int(d) for d in dims.split(",") if d)
+            for dims in re.findall(r"\w+\[([\d,]*)\]", shape)]
+
+
+def _row_ops(text, d_pad):
+    """Instructions of the optimized HLO outside fused computations whose
+    result or an operand holds d_pad elements: (name, opcode or custom-call
+    target, result shape, operand shapes, op_name)."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    shapes, instrs, comp = {}, [], ""
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        shape, opcode, operands, attrs = _split_shape(line[m.end():])
+        shapes[m.group(1)] = shape
+        if comp not in fused:
+            scope = re.search(r'op_name="([^"]*)"', attrs)
+            target = re.search(r'custom_call_target="([^"]*)"', attrs)
+            instrs.append((m.group(1), target.group(1) if target else opcode,
+                           shape, operands, scope.group(1) if scope else ""))
+    return [(name, op, shape, [shapes.get(o, "") for o in operands], scope)
+            for name, op, shape, operands, scope in instrs
+            if d_pad in _elements(shape)
+            or any(d_pad in _elements(shapes.get(o, "")) for o in operands)]
+
+
+@pytest.mark.parametrize("arch", ["qwen1.5-0.5b", "mamba2-370m"])
+def test_row_view_is_a_bitcast(one_chip, monkeypatch, arch):
+    """The train step of one node per chip (a small transformer, and a small
+    SSD model whose leaves do not all start on a 128-lane row) takes its
+    (1, D_pad) rows as a (D_pad // 128, 128) view and gives them back flat
+    by bitcasts: outside the compression (the kernel's own pad, relayout and
+    slice) no copy, transpose or reshape touches a row-sized array, and no
+    row-sized result is written in (1, 128) or (2, 128) tiles."""
+    from repro.configs.registry import get_config
+    from repro.core.triggers import constant
+    from repro.dist import sharding as sh
+    from repro.dist.sparq_dist import DistSparqConfig, build_sparq
+    from repro.launch.mesh import make_mesh
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    monkeypatch.setenv("REPRO_KERNEL_LOWERING", "pallas")
+    cfg = dataclasses.replace(get_config(arch).reduced(
+        n_layers=2, d_model=256, vocab=512), n_nodes=1)
+    mesh = sh.train_mesh(make_mesh((1, 1), ("data", "model"),
+                                   devices=list(one_chip.device_set)), cfg)
+    dcfg = DistSparqConfig(H=2, frac=0.1, threshold=constant(2.0),
+                           variant="ring", use_kernel=True)
+    init_fn, train_step, specs, _ = build_sparq(cfg, mesh, dcfg)
+    d_pad = train_step.d_pad
+    # the kernel pads the row to whole slabs, so its buffers are not
+    # row-sized and only the slice back to D_pad is
+    assert (d_pad // BLOCK) % BLOCK_ROWS
+    ssh = jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                       is_leaf=lambda x: isinstance(x, P))
+    state = jax.tree.map(
+        lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+        jax.eval_shape(init_fn, jax.random.PRNGKey(0)), ssh)
+    batch = {k: jax.ShapeDtypeStruct((1, 2, 64), jnp.int32,
+                                     sharding=NamedSharding(mesh, P("node")))
+             for k in ("tokens", "labels")}
+    text = jax.jit(train_step, donate_argnums=(0,)).lower(
+        state, batch).compile().as_text()
+    ops = [o for o in _row_ops(text, d_pad) if "sparq_compress" not in o[4]]
+    assert any(op == "fusion" for _, op, *_ in ops), ops
+    moves = [o for o in ops if o[1] in ("copy", "transpose", "reshape")]
+    assert not moves, moves
+    # (an async start's tuple holds its operand, which it only reads; the
+    # compiler joins a row it slices into fast memory by ConcatBitcast)
+    sparse = [o for o in ops
+              if o[1] not in ("parameter", "bitcast", "tuple",
+                              "get-tuple-element", "opt-barrier",
+                              "ConcatBitcast")
+              and not o[1].endswith("-start")
+              and d_pad in _elements(o[2])
+              and re.search(r"T\([12],128\)", o[2])]
+    assert not sparse, sparse
